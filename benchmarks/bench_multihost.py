@@ -147,7 +147,9 @@ def _free_port() -> int:
 
 
 def _launch_group(n_procs: int, sizes: list[int], art_dir: str,
-                  cache_dir: str | None, timeout: int) -> list[dict]:
+                  timeout: int) -> list[dict]:
+    from repro.launch.compile_cache import compile_cache_dir
+
     assert 8 % n_procs == 0
     os.makedirs(art_dir, exist_ok=True)
     port = _free_port()
@@ -162,9 +164,8 @@ def _launch_group(n_procs: int, sizes: list[int], art_dir: str,
                           f"{8 // n_procs}"),
             "MH_SIZES": json.dumps(sizes),
             "MH_ART_DIR": art_dir, "MH_OUT": out,
+            "JAX_COMPILATION_CACHE_DIR": compile_cache_dir(),
         })
-        if cache_dir:
-            env["JAX_COMPILATION_CACHE_DIR"] = cache_dir
         if n_procs > 1:
             env.update({
                 "GREENFLOW_COORDINATOR": f"localhost:{port}",
@@ -215,8 +216,7 @@ def _check_parity(ref: dict, children: list[dict]) -> None:
 
 def run(*, procs: tuple[int, ...] = (1, 2, 4, 8),
         sizes: list[int] | None = None, json_path: str | None = None,
-        cache_dir: str | None = None, trace_out: str | None = None,
-        timeout: int = 900) -> dict:
+        trace_out: str | None = None, timeout: int = 900) -> dict:
     from repro.obs.env import env_info
     from repro.obs.trace import merge_chrome_traces
 
@@ -228,7 +228,7 @@ def run(*, procs: tuple[int, ...] = (1, 2, 4, 8),
     ref_children: list[dict] | None = None
     for p in procs:
         art = os.path.join(base, f"p{p}")
-        children = _launch_group(p, sizes, art, cache_dir, timeout)
+        children = _launch_group(p, sizes, art, timeout)
         if p == 1:
             ref_children = children
         if ref_children is not None:
@@ -327,8 +327,6 @@ def main(argv=None):
                     help="P in {1, 2} with short windows (smoke)")
     ap.add_argument("--procs", type=int, nargs="+", default=None,
                     help="process counts to sweep (must divide 8)")
-    ap.add_argument("--cache-dir", default=None,
-                    help="JAX_COMPILATION_CACHE_DIR for the children")
     ap.add_argument("--trace-out", default=None,
                     help="merged Perfetto trace path")
     args = ap.parse_args(argv)
@@ -336,7 +334,7 @@ def main(argv=None):
         (1, 2) if args.fast else (1, 2, 4, 8))
     sizes = [64, 96, 64] if args.fast else None
     run(procs=procs, sizes=sizes, json_path=args.json,
-        cache_dir=args.cache_dir, trace_out=args.trace_out)
+        trace_out=args.trace_out)
 
 
 if __name__ == "__main__":

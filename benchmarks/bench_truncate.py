@@ -157,4 +157,7 @@ def main(argv=None):
 
 if __name__ == "__main__":
     sys.path.insert(0, os.path.join(REPO, "src"))
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     main()

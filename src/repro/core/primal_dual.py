@@ -75,7 +75,9 @@ def _option_prices(costs, lam, member):
 
     Scalar lam: lam * costs (the original Eq. 10 term).  Vector lam:
     sum_k lam_k * member_ik * C_mk - an (I, K) @ (K, M) matmul when
-    member is given, else the (M,) column combination C @ lam.
+    member is given, else the (M,) column combination C @ lam.  Both
+    run at full f32 precision: at default precision a TPU rounds the
+    priced costs to bf16, about three significant digits.
     """
     if jnp.ndim(lam) == 0:
         return lam * costs
@@ -85,8 +87,9 @@ def _option_prices(costs, lam, member):
             raise ValueError(             # constraints through member
                 f"cost map with {cm.shape[1]} columns cannot be priced "
                 f"by {lam.shape[0]} duals without a member matrix")
-        return cm @ lam
-    return member @ (cm * lam[None, :]).T
+        return jnp.matmul(cm, lam, precision=jax.lax.Precision.HIGHEST)
+    return jnp.matmul(member, (cm * lam[None, :]).T,
+                      precision=jax.lax.Precision.HIGHEST)
 
 
 @jax.jit
@@ -119,24 +122,36 @@ def consumption(rewards: jnp.ndarray, costs: jnp.ndarray,
     C[m*_i, k].  mask (I,) zeroes padded requests; axis_name sums across
     a request mesh axis (shard_map), so the padded + sharded fused
     pipeline sees the same window-global consumption as the host loop.
+
+    The spend is formed from per-option request COUNTS.  A count is a
+    sum of 0/1 terms, exact in float32 in any order, so the counts are
+    the same however the requests are split over shards or padded;
+    only the final cost-weighted (M,) sum rounds, and it runs on those
+    same counts everywhere.  Given the same rewards, the dual price is
+    then the same for any shard count of the request mesh, bit for bit.
     """
     j_star = allocate(rewards, costs, lam, member)
+    m_n = costs.shape[0]
+    hit = (j_star[:, None] == jnp.arange(m_n)[None, :]).astype(jnp.float32)
+    if mask is not None:
+        hit = hit * mask[:, None]
+
+    def counts(h):
+        cnt = jnp.sum(h, axis=0)  # (M,) requests per option
+        return cnt if axis_name is None else ordered_psum(cnt, axis_name)
+
     if jnp.ndim(lam) == 0:
-        taken = jnp.take(costs, j_star)
-        used = jnp.sum(taken if mask is None else taken * mask)
-    else:
-        taken = _as_cost_map(costs)[j_star]  # (I, K) or (I, 1)
-        # one (I,) reduction per constraint, not a (I, K) axis-0 sum:
-        # XLA lowers the two differently, and the K=1 column must run
-        # the scalar path's exact reduction to stay bit-identical
-        cols = []
-        for k in range(int(lam.shape[0])):
-            tk = taken[:, min(k, taken.shape[1] - 1)]
-            if member is not None:
-                tk = tk * member[:, k]
-            cols.append(jnp.sum(tk if mask is None else tk * mask))
-        used = jnp.stack(cols)
-    return used if axis_name is None else ordered_psum(used, axis_name)
+        return jnp.sum(counts(hit) * costs)
+    cm = _as_cost_map(costs)
+    cnt = None if member is not None else counts(hit)
+    # one (M,) reduction per constraint: the K=1 column runs the scalar
+    # path's exact expression and stays bit-identical to it
+    cols = []
+    for k in range(int(lam.shape[0])):
+        ck = cm[:, min(k, cm.shape[1] - 1)]
+        cnt_k = cnt if member is None else counts(hit * member[:, k:k + 1])
+        cols.append(jnp.sum(cnt_k * ck))
+    return jnp.stack(cols)
 
 
 def realized_reward(rewards: jnp.ndarray, j_star: jnp.ndarray) -> jnp.ndarray:

@@ -260,10 +260,13 @@ def reward_matrix_grouped(params: dict, cfg: RewardModelConfig,
                           plan: tuple) -> jnp.ndarray:
     """R in R^{I x J} with per-stage model-prefix deduplication.
 
-    Same output as ``reward_matrix`` (cells see identical inputs, so
+    Same values as ``reward_matrix`` (cells see identical inputs, so
     chains sharing a prefix get the shared result rather than J
-    recomputations); ``plan`` comes from ``chain_prefix_plan`` on the
-    chain set's ``chain_idx[:, :, 0]``.
+    recomputations) up to float32 rounding: the two paths run their
+    matmuls over different batch shapes, which XLA may block and
+    accumulate differently, so results agree to a few ulps (rtol 1e-6)
+    and not bit for bit.  ``plan`` comes from ``chain_prefix_plan`` on
+    the chain set's ``chain_idx[:, :, 0]``.
     """
     f = encode_context(params, raw_context)  # (I, d_f)
     i_n = f.shape[0]

@@ -118,11 +118,9 @@ def ring_allreduce_int8(flat_grads, mesh, *, axis: str = "data",
                         block: int = 2048):
     """Sum ``flat_grads`` (replicated layout, per-device distinct values is
     the caller's contract under shard_map-of-training) across ``axis``."""
-    from repro.distributed.compat import shard_map
-
     body = partial(_ring_allreduce_int8_body, axis=axis,
                    size=int(mesh.shape[axis]), block=block)
-    return shard_map(body, mesh=mesh, in_specs=P(axis),
+    return jax.shard_map(body, mesh=mesh, in_specs=P(axis),
                      out_specs=P(axis), check_vma=False)(flat_grads)
 
 

@@ -79,10 +79,11 @@ def initialize(*, coordinator: str | None = None,
         return False
     import jax
 
-    try:  # CPU backends stitch cross-host collectives over gloo
+    # CPU backends stitch cross-host collectives over gloo; accelerators
+    # keep their own transport.  Read from the platform setting, since
+    # the backend itself must not come up before the group is joined.
+    if "cpu" in (jax.config.jax_platforms or "").split(","):
         jax.config.update("jax_cpu_collectives_implementation", "gloo")
-    except Exception:  # non-CPU backend or older jax: default transport
-        pass
     jax.distributed.initialize(
         coordinator_address=coordinator,
         num_processes=int(num_processes),

@@ -47,7 +47,10 @@ def ordered_psum(x, axis_name: str):
     return jnp.sum(jax.lax.all_gather(x, axis_name), axis=0)
 
 
-from repro.distributed.compat import current_mesh  # noqa: F401 (re-export)
+def current_mesh():
+    """The ambient mesh set by ``jax.set_mesh``, or None."""
+    m = jax.sharding.get_abstract_mesh()
+    return m if m.axis_names else None
 
 
 def mesh_axes(mesh=None) -> tuple:

@@ -131,9 +131,9 @@ def _train_model(loss_fn, params, pipe_fn, steps, batch, seed, lr=3e-3):
     return state.params, losses
 
 
-def train_cascade_models(world: World, users: np.ndarray,
-                         cfg: ExperimentConfig) -> CascadeModels:
-    w = world.cfg
+def cascade_configs(w: WorldConfig) -> tuple:
+    """The four stage models' configs (DSSM, YDNN, DIN, DIEN) for a
+    world - the widths every serving stack over that world runs."""
     n_uf = w.n_user_fields
     user_vocab = n_uf * w.user_field_vocab
 
@@ -155,6 +155,12 @@ def train_cascade_models(world: World, users: np.ndarray,
                                user_vocab=user_vocab, n_user_fields=n_uf,
                                embed_dim=8, seq_len=w.hist_len,
                                attn_hidden=(16, 8), mlp_hidden=(32, 16))
+    return dssm_cfg, ydnn_cfg, din_cfg, dien_cfg
+
+
+def train_cascade_models(world: World, users: np.ndarray,
+                         cfg: ExperimentConfig) -> CascadeModels:
+    dssm_cfg, ydnn_cfg, din_cfg, dien_cfg = cascade_configs(world.cfg)
     key = jax.random.PRNGKey(cfg.seed)
 
     def pipe(rng):
@@ -241,6 +247,16 @@ def build_experiment(cfg: ExperimentConfig = ExperimentConfig(),
 # ---------------------------------------------------------------------------
 
 
+def reward_config(chains: ActionChainSet, d_context: int, *,
+                  recursive: bool = True,
+                  multi_basis: bool = True) -> RewardModelConfig:
+    """The reward model's widths over a chain set and context size."""
+    return RewardModelConfig(
+        n_stages=chains.n_stages, max_models=2, n_scale_groups=4,
+        d_context=d_context, d_feature=32, d_hidden=32, d_state=16,
+        recursive=recursive, multi_basis=multi_basis)
+
+
 def train_reward_model(exp: Experiment, *, recursive: bool = True,
                        multi_basis: bool = True, steps: int | None = None,
                        seed: int = 0) -> tuple[dict, RewardModelConfig]:
@@ -263,10 +279,8 @@ def train_reward_model(exp: Experiment, *, recursive: bool = True,
     """
     cfg = exp.cfg
     chains = exp.chains
-    rcfg = RewardModelConfig(
-        n_stages=chains.n_stages, max_models=2, n_scale_groups=4,
-        d_context=exp.ctx_reward.shape[1], d_feature=32, d_hidden=32,
-        d_state=16, recursive=recursive, multi_basis=multi_basis)
+    rcfg = reward_config(chains, exp.ctx_reward.shape[1],
+                         recursive=recursive, multi_basis=multi_basis)
     params = reward_model_init(jax.random.PRNGKey(seed + 33), rcfg)
     steps = steps or cfg.reward_steps
 
@@ -397,6 +411,25 @@ def serve_config(*, small: bool = False) -> ExperimentConfig:
         reward_steps=200 if small else 400, batch=48)
 
 
+def _source_digest() -> str:
+    """Hash of this package's Python sources, so that a pickled
+    experiment is only ever read back by the code that built it."""
+    import hashlib
+    import os
+
+    root = os.path.dirname(os.path.abspath(__file__))
+    h = hashlib.sha256()
+    for dirpath, dirnames, filenames in os.walk(root):
+        dirnames.sort()
+        for name in sorted(filenames):
+            if name.endswith(".py"):
+                path = os.path.join(dirpath, name)
+                h.update(os.path.relpath(path, root).encode())
+                with open(path, "rb") as f:
+                    h.update(f.read())
+    return h.hexdigest()[:16]
+
+
 def build_serving_stack(cfg: ExperimentConfig | None = None, *,
                         small: bool = False, cache: bool = True,
                         verbose: bool = False):
@@ -405,7 +438,9 @@ def build_serving_stack(cfg: ExperimentConfig | None = None, *,
     ``benchmarks/bench_serve.py``.  Returns (exp, server, params, rcfg).
 
     The built experiment (not the reward model - it trains in seconds)
-    is pickled under results/cache keyed by every size-relevant field.
+    is pickled under results/cache, keyed by every size-relevant field
+    and by a hash of the package's sources; ``cache=False`` builds from
+    the seed and touches no file.
     """
     import os
     import pickle
@@ -420,7 +455,7 @@ def build_serving_stack(cfg: ExperimentConfig | None = None, *,
         key = (f"serve_u{w.n_users}_i{w.n_items}_h{w.hist_len}"
                f"_ws{w.seed}_s{cfg.seed}_c{cfg.cascade_steps}"
                f"_e{cfg.expose}_ns{cfg.n_scales}_b{cfg.batch}"
-               f"_r{cfg.reward_steps}.pkl")
+               f"_r{cfg.reward_steps}_src{_source_digest()}.pkl")
         root = os.path.join(os.path.dirname(__file__), "..", "..",
                             "results", "cache")
         os.makedirs(root, exist_ok=True)

@@ -34,8 +34,7 @@ RESULTS_DIR = os.path.join(os.path.dirname(__file__), "..", "..", "..",
 def _measure(cell, mesh) -> dict:
     """Lower + compile one cell on one mesh; return all analyses."""
     t0 = time.time()
-    from repro.distributed.compat import mesh_context
-    with mesh_context(mesh):
+    with jax.set_mesh(mesh):
         in_sh = tree_named_shardings(cell.in_shardings, mesh)
         out_sh = (tree_named_shardings(cell.out_shardings, mesh)
                   if cell.out_shardings is not None else None)

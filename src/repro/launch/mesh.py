@@ -11,9 +11,15 @@ importing this module touches no jax device state.
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
 from jax.sharding import PartitionSpec as P
 
-from repro.distributed.compat import make_mesh, mesh_context  # noqa: F401
+
+def make_mesh(shape: tuple, axes: tuple):
+    """``jax.make_mesh`` with Auto axis types: shardings propagate as
+    under ``jit``, and ``shard_map`` bodies see plain local arrays."""
+    return jax.make_mesh(tuple(shape), tuple(axes),
+                         axis_types=(AxisType.Auto,) * len(shape))
 
 
 def make_production_mesh(*, multi_pod: bool = False):

@@ -126,14 +126,18 @@ every host's tracks in one Perfetto timeline.  Elastic resharding
 from __future__ import annotations
 
 import argparse
+from dataclasses import dataclass
 
 import numpy as np
 
+from repro.carbon.ledger import DEFAULT_EMBODIED_G_PER_DEVICE_H
 from repro.core.pfec import pfec_report
 from repro.experiments import build_serving_stack, serve_config
+from repro.launch.compile_cache import enable_compile_cache
 from repro.obs.events import _host_np
 from repro.serving.pipeline import ServingPipeline
-from repro.serving.stream import SCENARIOS, TrafficScenario, run_stream
+from repro.serving.stream import (SCENARIOS, StreamStats, TrafficScenario,
+                                  run_stream)
 
 
 def _f(x) -> float:
@@ -244,10 +248,9 @@ def _carbon_stream(server, params, rcfg, sizes, cb, ledger,
               f"{_f(r.spend) / r.budget:>13.3f} "
               f"{_f(r.lam_after):>12.3e} {int(r.downgraded):>10d} "
               f"{r.revenue_np.sum():>9.1f} {st.dispatch_ms[t]:>11.2f}")
-    total_flops = float(sum(_f(r.flops) for r in st.windows))
     print(f"[serve] {len(sizes)} windows in {st.wall_s:.2f}s "
           f"({len(sizes) / st.wall_s:.1f} win/s)")
-    return st.total_revenue, total_flops
+    return st
 
 
 def _geo_stream(chains, server, params, rcfg, sizes, flops_budget, args,
@@ -304,7 +307,6 @@ def _geo_stream(chains, server, params, rcfg, sizes, flops_budget, args,
                         embodied_g_per_device_h=args.embodied_g_per_device_h,
                         n_devices=args.devices)
         for r in names}
-    total_rev = total_flops = 0.0
     for t, r in enumerate(st.windows):
         regions = r.regions_np
         dec = r.decisions_np
@@ -319,8 +321,6 @@ def _geo_stream(chains, server, params, rcfg, sizes, flops_budget, args,
               f"{r.revenue_np.sum():>9.1f} {st.dispatch_ms[t]:>11.2f}")
         for k, n_ in enumerate(names):
             ledgers[n_].record(dec[regions == k], t=t, ci=ci_w[n_][t])
-        total_rev += float(r.revenue_np.sum())
-        total_flops += _f(r.flops)
     print(f"[serve] {n_w} windows in {st.wall_s:.2f}s "
           f"({n_w / st.wall_s:.1f} win/s)")
     report_path = args.carbon_report or os.path.join(
@@ -335,7 +335,7 @@ def _geo_stream(chains, server, params, rcfg, sizes, flops_budget, args,
               f"{rep['embodied_gco2e']:.4e} g embodied = "
               f"{rep['total_gco2e']:.4e} gCO2e "
               f"({rep['n_requests']} requests)")
-    return total_rev, total_flops
+    return st
 
 
 def _geotenants_stream(chains, server, params, rcfg, sizes,
@@ -413,7 +413,6 @@ def _geotenants_stream(chains, server, params, rcfg, sizes,
                         embodied_g_per_device_h=args.embodied_g_per_device_h,
                         n_devices=args.devices)
         for r in names}
-    total_rev = total_flops = 0.0
     tenant_spend = np.zeros(t_n)
     for t, r in enumerate(st.windows):
         regions = r.regions_np
@@ -430,8 +429,6 @@ def _geotenants_stream(chains, server, params, rcfg, sizes,
               f"{st.dispatch_ms[t]:>11.2f}")
         for k, n_ in enumerate(names):
             ledgers[n_].record(dec[regions == k], t=t, ci=ci_w[n_][t])
-        total_rev += float(r.revenue_np.sum())
-        total_flops += _f(r.flops)
     print(f"[serve] {n_w} windows in {st.wall_s:.2f}s "
           f"({n_w / st.wall_s:.1f} win/s)")
     print("[serve] day totals, per tenant (spend_g / budget_g): "
@@ -443,7 +440,7 @@ def _geotenants_stream(chains, server, params, rcfg, sizes,
     geo_report_csv(ledgers, report_path)
     print(f"[serve] per-region carbon ledger -> "
           f"{os.path.abspath(report_path)}")
-    return total_rev, total_flops
+    return st
 
 
 def _legacy_carbon_loop(exp, server, params, rcfg, sizes, cb, ledger,
@@ -474,7 +471,7 @@ def _legacy_carbon_loop(exp, server, params, rcfg, sizes, cb, ledger,
     return total_rev, total_flops
 
 
-def main():
+def build_parser() -> argparse.ArgumentParser:
     ap = argparse.ArgumentParser(
         description="GreenFlow streaming serving (fused pipeline)")
     ap.add_argument("--windows", type=int, default=12)
@@ -550,7 +547,8 @@ def main():
     ap.add_argument("--region-cap-frac", type=float, default=0.6,
                     help="geotenants: each region's per-window gram cap "
                          "as a fraction of the total tenant grams")
-    ap.add_argument("--embodied-g-per-device-h", type=float, default=None,
+    ap.add_argument("--embodied-g-per-device-h", type=float,
+                    default=DEFAULT_EMBODIED_G_PER_DEVICE_H,
                     help="embodied-carbon amortization per device-hour "
                          "(default: the ichnos-style server constant; "
                          "0 disables the ledger line)")
@@ -560,9 +558,6 @@ def main():
     ap.add_argument("--prefetch", type=int, default=2,
                     help="window-prep prefetch queue depth (0 = the "
                          "sequential double-buffered reference path)")
-    ap.add_argument("--cache-dir", default=None,
-                    help="persistent JAX compilation-cache directory: "
-                         "repeat runs skip XLA compiles entirely")
     ap.add_argument("--metrics-out", default=None,
                     help="write a Prometheus-text metrics snapshot here "
                          "at exit (plus a JSON snapshot at PATH.json and "
@@ -580,64 +575,26 @@ def main():
                     help="run under jax.profiler.trace writing here; "
                          "host spans become TraceAnnotations lined up "
                          "against XLA device events")
-    args = ap.parse_args()
-    multihost = False
-    host = None
-    if args.processes > 1 or args.coordinator:
-        from repro.distributed import multihost as mh
-        if args.legacy:
-            raise SystemExit("--processes runs the fused SPMD pipeline; "
-                             "--legacy is single-process")
-        if args.source == "table":
-            raise SystemExit("--processes needs a streaming --source "
-                             "(generated or memmap): every host "
-                             "generates its own slice of each window")
-        if args.shards > 0:
-            raise SystemExit("--shards picks a device subset; with "
-                             "--processes the mesh is always the full "
-                             "process-spanning device set (drop "
-                             "--shards)")
-        multihost = mh.initialize(
-            coordinator=args.coordinator,
-            num_processes=args.processes or None,
-            process_id=args.process_id)
-        if not multihost:
-            raise SystemExit("--processes > 1 needs a --coordinator "
-                             "(or $GREENFLOW_COORDINATOR)")
-        host = mh.host_label()
-        # per-host artifact files: suffix every output with the label
-        for attr in ("metrics_out", "trace_out"):
-            if getattr(args, attr):
-                setattr(args, attr, getattr(args, attr) + "." + host)
-        print(f"[serve] multihost: {mh.host_report()}")
-    if args.cache_dir:
-        import jax
-        jax.config.update("jax_compilation_cache_dir", args.cache_dir)
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
-        jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
-        print(f"[serve] jax compilation cache -> {args.cache_dir}")
-    if args.embodied_g_per_device_h is None:  # resolved ONCE for every
-        from repro.carbon.ledger import \
-            DEFAULT_EMBODIED_G_PER_DEVICE_H  # scenario that meters it
-        args.embodied_g_per_device_h = DEFAULT_EMBODIED_G_PER_DEVICE_H
+    return ap
 
-    obs = None
-    if (args.metrics_out or args.trace_out or args.obs_interval
-            or args.profile_dir):
-        from repro.obs import Obs, WindowEventLog
-        obs = Obs(events=(WindowEventLog(args.metrics_out
-                                         + ".windows.jsonl")
-                          if args.metrics_out else None),
-                  interval=args.obs_interval,
-                  annotate=bool(args.profile_dir), host=host)
-    if args.profile_dir:
-        import jax
-        jax.profiler.start_trace(args.profile_dir)
-        print(f"[obs] jax profiler trace -> {args.profile_dir}")
 
-    print("[serve] building world + training cascade & reward models ...")
-    exp, server, params, rcfg = build_serving_stack(
-        serve_config(small=args.small), verbose=True)
+@dataclass
+class ServeRun:
+    """What one ``serve`` call streamed: the StreamStats of every
+    pipeline it ran (empty for the --legacy host loops) and the run's
+    total clicks and FLOPs."""
+
+    streams: list[StreamStats]
+    total_revenue: float
+    total_flops: float
+
+
+def serve(args, stack, *, obs=None, multihost: bool = False) -> ServeRun:
+    """Stream the scenario that ``args`` (from ``build_parser``) names
+    over ``stack`` = (exp, server, params, rcfg), the tuple
+    ``experiments.build_serving_stack`` returns."""
+    exp, server, params, rcfg = stack
+    streams = []
     chains = exp.chains
     budget = args.budget_frac * chains.costs.max() * args.requests
     n_tenants = (args.tenants
@@ -728,11 +685,11 @@ def main():
                 exp, server, params, rcfg, sizes, cb, ledger,
                 sample_window, args.carbon_pricing)
         else:
-            total_rev, total_flops = _carbon_stream(
+            streams = [_carbon_stream(
                 server, params, rcfg, sizes, cb, ledger,
                 sample_window, args.carbon_pricing, mesh=mesh,
                 forecast=args.ci_forecast, prefetch=args.prefetch,
-                obs=obs, wrap_source=wrap_source)
+                obs=obs, wrap_source=wrap_source)]
         report_path = args.carbon_report or os.path.join(
             os.path.dirname(__file__), "..", "..", "..", "results",
             "carbon_report.csv")
@@ -757,17 +714,17 @@ def main():
         if args.legacy:
             raise SystemExit("--scenario georegions has no legacy loop "
                              "(the router exists only in the fused pass)")
-        total_rev, total_flops = _geo_stream(
+        streams = [_geo_stream(
             chains, server, params, rcfg, sizes, float(budget), args,
-            sample_window, mesh=mesh, obs=obs, wrap_source=wrap_source)
+            sample_window, mesh=mesh, obs=obs, wrap_source=wrap_source)]
     elif args.scenario == "geotenants":
         if args.legacy:
             raise SystemExit("--scenario geotenants has no legacy loop "
                              "(the combined tenant x region pass exists "
                              "only in the fused pipeline)")
-        total_rev, total_flops = _geotenants_stream(
+        streams = [_geotenants_stream(
             chains, server, params, rcfg, sizes, float(budget), args,
-            sample_window, mesh=mesh, obs=obs, wrap_source=wrap_source)
+            sample_window, mesh=mesh, obs=obs, wrap_source=wrap_source)]
     elif args.legacy:
         total_rev, total_flops = _legacy_loop(exp, server, params, rcfg,
                                               sizes, budget)
@@ -780,15 +737,11 @@ def main():
             pipes = [ServingPipeline(server, params, rcfg,
                                      budget / n_tenants, obs=obs)
                      for _ in range(n_tenants)]
-            stats = []
-            for p in pipes:
-                stats.append(run_stream(
-                    p, [n // n_tenants for n in sizes], sample_window,
-                    prefetch=args.prefetch, obs=obs))
-            total_rev = sum(s.total_revenue for s in stats)
-            total_flops = sum(s.total_spend for s in stats)
+            streams = [run_stream(
+                p, [n // n_tenants for n in sizes], sample_window,
+                prefetch=args.prefetch, obs=obs) for p in pipes]
             for t in range(len(sizes)):
-                spends = [float(s.windows[t].spend) for s in stats]
+                spends = [float(s.windows[t].spend) for s in streams]
                 print(f"win {t:>3}: per-tenant spend/budget "
                       + " ".join(f"{sp / (budget / n_tenants):.3f}"
                                  for sp in spends))
@@ -805,7 +758,7 @@ def main():
                 sample_window = wrap_source(pipe, sample_window)
             st = run_stream(pipe, sizes, sample_window,
                             prefetch=args.prefetch, obs=obs)
-            total_rev, total_flops = st.total_revenue, st.total_spend
+            streams = [st]
             priced = tb is not None and args.tenant_mode == "priced"
             lam_hdr = "lam(per-tenant)" if priced else "lam"
             print(f"{'win':>4} {'n':>5} {'spend/budget':>13} "
@@ -827,9 +780,65 @@ def main():
             print(f"[serve] {len(sizes)} windows in {st.wall_s:.2f}s "
                   f"({len(sizes) / st.wall_s:.1f} win/s), worst overshoot "
                   f"vs cap: {st.overshoot(c_min) * 100:.3f}%")
+    if streams:
+        total_rev = sum(st.total_revenue for st in streams)
+        total_flops = sum(_f(r.flops) for st in streams
+                          for r in st.windows)
+    return ServeRun(streams, float(total_rev), float(total_flops))
+
+
+def main(argv=None):
+    args = build_parser().parse_args(argv)
+    multihost = False
+    host = None
+    if args.processes > 1 or args.coordinator:
+        from repro.distributed import multihost as mh
+        if args.legacy:
+            raise SystemExit("--processes runs the fused SPMD pipeline; "
+                             "--legacy is single-process")
+        if args.source == "table":
+            raise SystemExit("--processes needs a streaming --source "
+                             "(generated or memmap): every host "
+                             "generates its own slice of each window")
+        if args.shards > 0:
+            raise SystemExit("--shards picks a device subset; with "
+                             "--processes the mesh is always the full "
+                             "process-spanning device set (drop "
+                             "--shards)")
+        multihost = mh.initialize(
+            coordinator=args.coordinator,
+            num_processes=args.processes or None,
+            process_id=args.process_id)
+        if not multihost:
+            raise SystemExit("--processes > 1 needs a --coordinator "
+                             "(or $GREENFLOW_COORDINATOR)")
+        host = mh.host_label()
+        # per-host artifact files: suffix every output with the label
+        for attr in ("metrics_out", "trace_out"):
+            if getattr(args, attr):
+                setattr(args, attr, getattr(args, attr) + "." + host)
+        print(f"[serve] multihost: {mh.host_report()}")
+    print(f"[serve] jax compilation cache -> {enable_compile_cache()}")
+    obs = None
+    if (args.metrics_out or args.trace_out or args.obs_interval
+            or args.profile_dir):
+        from repro.obs import Obs, WindowEventLog
+        obs = Obs(events=(WindowEventLog(args.metrics_out
+                                         + ".windows.jsonl")
+                          if args.metrics_out else None),
+                  interval=args.obs_interval,
+                  annotate=bool(args.profile_dir), host=host)
+    if args.profile_dir:
+        import jax
+        jax.profiler.start_trace(args.profile_dir)
+        print(f"[obs] jax profiler trace -> {args.profile_dir}")
+
+    print("[serve] building world + training cascade & reward models ...")
+    stack = build_serving_stack(serve_config(small=args.small), verbose=True)
+    run = serve(args, stack, obs=obs, multihost=multihost)
 
     print("\n[serve] PFEC (GreenFlow serving run):")
-    rep = pfec_report(clicks=float(total_rev), flops=float(total_flops))
+    rep = pfec_report(clicks=run.total_revenue, flops=run.total_flops)
     for k, v in rep.as_row().items():
         print(f"    {k:14s} {v}")
 

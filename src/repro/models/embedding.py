@@ -133,9 +133,7 @@ def sharded_embedding_apply(table: jnp.ndarray, ids: jnp.ndarray, mesh,
         return shard_local_lookup(tbl, local_ids, shard_idx, rows_per_shard,
                                   axis, out_dtype)
 
-    from repro.distributed.compat import shard_map
-
-    return shard_map(
+    return jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(axis, None), batch_spec),
         out_specs=batch_spec,
@@ -190,9 +188,7 @@ def sharded_embedding_apply_2d(table: jnp.ndarray, ids: jnp.ndarray, mesh,
                                         tiled=True)
         return jax.lax.psum(rows, axes[0])
 
-    from repro.distributed.compat import shard_map
-
-    return shard_map(
+    return jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(axes, None), P(None)),
         out_specs=P(batch_axes if batch_axes else None, None),

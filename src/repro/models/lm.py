@@ -464,9 +464,7 @@ def _moe_ep(p, cfg: LMConfig, x):
     w3 = p.get("w3", p["w1"])  # dummy when ungated
     baxes = bspec if isinstance(bspec, tuple) else ((bspec,) if bspec else ())
     body = partial(_moe_ep_body, cfg=cfg, axis="model", batch_axes=baxes)
-    from repro.distributed.compat import shard_map
-
-    out, aux = shard_map(
+    out, aux = jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(bspec, None), P(None, None), P("model", None, None),
                   P("model", None, None), P("model", None, None)),

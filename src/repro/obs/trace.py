@@ -30,6 +30,8 @@ import os
 import threading
 import time
 
+from jax.profiler import TraceAnnotation
+
 
 class _NullSpan:
     """Shared no-op span of a disabled tracer (stateless, reentrant)."""
@@ -57,10 +59,8 @@ class _Span:
 
     def __enter__(self):
         if self.tracer.annotate:
-            ann = _trace_annotation(self.name)
-            if ann is not None:
-                self.annotation = ann
-                ann.__enter__()
+            self.annotation = TraceAnnotation(self.name)
+            self.annotation.__enter__()
         self.t0 = self.tracer.clock_ns()
         return self
 
@@ -74,16 +74,6 @@ class _Span:
             (self.name, th.ident, th.name, self.t0, t1 - self.t0,
              self.args))
         return False
-
-
-def _trace_annotation(name):
-    """A ``jax.profiler.TraceAnnotation`` when jax is importable (pass-
-    through so host spans appear inside a jax.profiler device trace)."""
-    try:
-        from jax.profiler import TraceAnnotation
-    except Exception:  # jax absent or too old: spans still record
-        return None
-    return TraceAnnotation(name)
 
 
 class Tracer:

@@ -17,7 +17,16 @@ A map of the unified allocator core and the layers over it:
       (the paper, K=1, bit-identical), a (K,) price vector against an
       (M, K) option->constraint cost map (K over tenant x region), and
       per-request constraint membership.  ``window_step`` is the shared
-      host-loop body the budget controllers wrap.
+      host-loop body the budget controllers wrap.  ``consumption``
+      forms each constraint's spend from per-option request COUNTS
+      (sums of 0/1 terms, exact in any order), so padding a window or
+      splitting it over more request shards changes no price by
+      itself.  Reward scoring runs its matmuls at each shard's batch
+      shape, which XLA may round differently, so across shard COUNTS
+      rewards and, through them, prices agree to float32 rounding
+      while decisions are checked equal (``chip_smoke.py --chips 4``,
+      tests/test_serving.py); ``reward_matrix_grouped`` likewise
+      equals ``reward_matrix`` to rtol 1e-6, not bit for bit.
   serving.guard           the budget downgrade guard as a vectorized,
       jit-compatible pass: cumsum tail-reserve walk, mask-aware for
       padded windows, shardable over the request axis, and -

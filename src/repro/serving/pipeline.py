@@ -101,7 +101,6 @@ from repro.core.primal_dual import DualDescentConfig, allocate, dual_descent
 from repro.core.reward_model import (RewardModelConfig, chain_prefix_plan,
                                      denormalize_rewards,
                                      reward_matrix_grouped)
-from repro.distributed.compat import shard_map
 from repro.distributed.sharding import REQUEST_AXIS as AXIS
 from repro.distributed.sharding import ordered_psum
 from repro.serving.guard import (_exclusive_shard_offset, downgrade_guard,
@@ -345,6 +344,11 @@ class ServingPipeline:
             self.lam = jnp.full(cs.n_prices, lam_init, jnp.float32)
         else:
             self.lam = jnp.float32(lam_init)
+        if mesh is not None and not self.multihost:
+            # the dual fn publishes a mesh-replicated price: start the
+            # chain there, so window 1 reuses window 0's compiled pass
+            self.lam = jax.device_put(
+                self.lam, jax.sharding.NamedSharding(mesh, P()))
         # with donation the chain buffer ``self.lam`` is consumed by the
         # next window's dual dispatch; ``_lam_rec`` is its always-
         # readable twin (a bitwise device copy) that WindowResult
@@ -537,7 +541,12 @@ class ServingPipeline:
                         ).astype(jnp.float32)
                 oh_r = (region[:, None] == jnp.arange(r_n)[None, :]
                         ).astype(jnp.float32)
-                tr_spend = (oh_t * cd[:, None]).T @ oh_r  # (T, R)
+                # full f32 matmul: at default precision a TPU rounds the
+                # gram costs to bf16 (~3 digits) and the reported spend
+                # would no longer be the one the guard enforced
+                tr_spend = jnp.matmul(
+                    (oh_t * cd[:, None]).T, oh_r,
+                    precision=jax.lax.Precision.HIGHEST)  # (T, R)
                 if axis is not None:
                     tr_spend = ordered_psum(tr_spend, axis)
                 spend = jnp.sum(tr_spend)
@@ -550,8 +559,8 @@ class ServingPipeline:
                         jnp.sum(tr_spend, axis=0), tr_spend)
 
             if self.mesh is not None:
-                fn = shard_map(
-                    fn, mesh=self.mesh,
+                fn = jax.shard_map(
+                    fn, mesh=self.mesh, check_vma=False,
                     in_specs=(P(), tspec, P(AXIS), P(AXIS), P(AXIS),
                               P(AXIS), P(), P(), P()),
                     out_specs=(P(AXIS), P(AXIS), P(AXIS), P(), P(), P(),
@@ -648,8 +657,8 @@ class ServingPipeline:
                         regions, region_spend)
 
             if self.mesh is not None:
-                fn = shard_map(
-                    fn, mesh=self.mesh,
+                fn = jax.shard_map(
+                    fn, mesh=self.mesh, check_vma=False,
                     in_specs=(P(), tspec, P(AXIS), P(AXIS), P(AXIS),
                               P(), P(), P()),
                     out_specs=(P(AXIS), P(AXIS), P(AXIS), P(), P(), P(),
@@ -693,8 +702,8 @@ class ServingPipeline:
                         None, None)
 
             if self.mesh is not None:
-                fn = shard_map(
-                    fn, mesh=self.mesh,
+                fn = jax.shard_map(
+                    fn, mesh=self.mesh, check_vma=False,
                     in_specs=(P(), tspec, P(AXIS), P(AXIS), P(AXIS),
                               P(AXIS), P(), P(), P()),
                     out_specs=(P(AXIS), P(AXIS), P(AXIS), P(), P(), P(),
@@ -722,8 +731,8 @@ class ServingPipeline:
             return rewards, dec, rev, spend, flops, dg, None, None, None
 
         if self.mesh is not None:
-            fn = shard_map(
-                fn, mesh=self.mesh,
+            fn = jax.shard_map(
+                fn, mesh=self.mesh, check_vma=False,
                 in_specs=(P(), tspec, P(AXIS), P(AXIS), P(AXIS), P(), P(),
                           P()),
                 out_specs=(P(AXIS), P(AXIS), P(AXIS), P(), P(), P(), P(),
@@ -777,7 +786,7 @@ class ServingPipeline:
                 return lam_new
 
             if self.mesh is not None:
-                fn = shard_map(fn, mesh=self.mesh,
+                fn = jax.shard_map(fn, mesh=self.mesh, check_vma=False,
                                in_specs=(P(AXIS), P(AXIS), P(AXIS), P(),
                                          P(), P()),
                                out_specs=P())
@@ -796,7 +805,7 @@ class ServingPipeline:
                 return lam_new
 
             if self.mesh is not None:
-                fn = shard_map(fn, mesh=self.mesh,
+                fn = jax.shard_map(fn, mesh=self.mesh, check_vma=False,
                                in_specs=(P(AXIS), P(AXIS), P(), P(),
                                          P()),
                                out_specs=P())
@@ -814,7 +823,7 @@ class ServingPipeline:
                 return lam_new
 
             if self.mesh is not None:
-                fn = shard_map(fn, mesh=self.mesh,
+                fn = jax.shard_map(fn, mesh=self.mesh, check_vma=False,
                                in_specs=(P(AXIS), P(AXIS), P(AXIS), P(),
                                          P(), P()),
                                out_specs=P())
@@ -829,7 +838,7 @@ class ServingPipeline:
             return lam_new
 
         if self.mesh is not None:
-            fn = shard_map(fn, mesh=self.mesh,
+            fn = jax.shard_map(fn, mesh=self.mesh, check_vma=False,
                            in_specs=(P(AXIS), P(AXIS), P(), P(), P()),
                            out_specs=P())
         return _jit(fn, 2)

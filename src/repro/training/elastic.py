@@ -43,7 +43,7 @@ class ElasticController:
         self.events: list[ElasticEvent] = []
 
     def make_mesh(self, shape: tuple):
-        from repro.distributed.compat import make_mesh
+        from repro.launch.mesh import make_mesh
         return make_mesh(tuple(shape), self.axis_names)
 
     def remesh_restore(self, ckpt_dir: str, target_state, shardings,

@@ -313,7 +313,7 @@ def test_audit_clean_toy_passes_and_sees_donation():
 
 
 def test_audit_catches_f64_upcast():
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         fn = jax.jit(lambda x: x.astype(jnp.float64) * 2.0)
         res = audit_jitted(fn, (jnp.ones((4,), jnp.float32),))
     assert not res.ok

@@ -34,7 +34,7 @@ def test_distributed_semantics_bundle():
     out = _run("""
     import jax, jax.numpy as jnp, numpy as np, tempfile
     from jax.sharding import PartitionSpec as P
-    from repro.distributed.compat import make_mesh, mesh_context
+    from repro.launch.mesh import make_mesh
 
     mesh = make_mesh((2, 4), ("data", "model"))
 
@@ -42,7 +42,7 @@ def test_distributed_semantics_bundle():
     from repro.models.embedding import sharded_embedding_apply
     table = jax.random.normal(jax.random.PRNGKey(0), (40, 8))
     ids = jax.random.randint(jax.random.PRNGKey(1), (16,), 0, 40)
-    with mesh_context(mesh):
+    with jax.set_mesh(mesh):
         got = jax.jit(lambda t, i: sharded_embedding_apply(
             t, i, mesh, axis="model", batch_axes=("data",)))(table, ids)
     assert np.allclose(np.asarray(got), np.asarray(table)[np.asarray(ids)],
@@ -60,7 +60,7 @@ def test_distributed_semantics_bundle():
     p = lm.init(jax.random.PRNGKey(2), cfg)
     toks = jax.random.randint(jax.random.PRNGKey(3), (4, 8), 0, 64)
     ref, _ = lm.forward(p, cfg, toks)
-    with mesh_context(mesh):
+    with jax.set_mesh(mesh):
         got, _ = jax.jit(lambda pp, t: lm.forward(pp, cfg, t))(p, toks)
     err = float(jnp.abs(ref - got).max())
     assert err < 1e-4, f"EP MoE err {err}"
@@ -73,7 +73,7 @@ def test_distributed_semantics_bundle():
                        fsdp=True, sequence_parallel=True)
     dp = lm.init(jax.random.PRNGKey(4), dcfg)
     ref, _ = lm.forward(dp, dcfg, toks)
-    with mesh_context(mesh):
+    with jax.set_mesh(mesh):
         got, _ = jax.jit(lambda pp, t: lm.forward(pp, dcfg, t))(dp, toks)
     err = float(jnp.abs(ref - got).max())
     assert err < 1e-4, f"dense LM err {err}"
@@ -120,7 +120,7 @@ def test_mini_dryrun_smoke_arch():
     from repro.configs import get_arch
     from repro.launch.dryrun import _measure
     from repro.launch.mesh import tree_named_shardings
-    from repro.distributed.compat import make_mesh
+    from repro.launch.mesh import make_mesh
 
     mesh = make_mesh((2, 4), ("data", "model"))
     cell = get_arch("greenflow-cascade").make_cell("reward_serve")

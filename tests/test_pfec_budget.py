@@ -99,10 +99,15 @@ def test_budget_controller_guard_caps_spend():
     for _ in range(4):
         decisions = ctl.step_window(rewards + rng.normal(0, 0.01, rewards.shape))
         assert ctl.stats[-1].spend <= budget * (1 + 1e-6)
-    # traffic spike: 5x requests.  The guard caps spend at the budget OR
-    # the physical floor (every request on the cheapest chain - Eq. 3b
-    # serves everyone; the paper calls this "computation downgrade").
-    spike = np.tile(rewards, (5, 1))
+    # traffic spike: 5x requests whose rewards still favour the most
+    # expensive chain at the price the controller holds now, so Eq. 10
+    # overspends and the guard must step in.  It caps spend at the
+    # budget OR the physical floor (every request on the cheapest chain
+    # - Eq. 3b serves everyone; the paper calls this "computation
+    # downgrade").
+    lam = float(ctl.pd.lam)
+    slope = 2.0 * (lam + 1.0 / chains.costs.max())  # score_j ~ c_j
+    spike = np.tile(slope * chains.costs, (5 * n, 1))
     ctl.step_window(spike.astype(np.float32))
     cap = max(budget, floor_per_req * len(spike))
     assert ctl.stats[-1].spend <= cap * (1 + 1e-6)

@@ -153,8 +153,10 @@ def test_reward_matrix_grouped_matches_full(system_exp):
             params = reward_model_init(jax.random.PRNGKey(7), cfg)
             full = reward_matrix(params, cfg, ctx, mo, sh)
             grouped = reward_matrix_grouped(params, cfg, ctx, sh, plan)
-            np.testing.assert_array_equal(np.asarray(full),
-                                          np.asarray(grouped))
+            # different matmul batch shapes: equal up to f32 rounding
+            np.testing.assert_allclose(np.asarray(grouped),
+                                       np.asarray(full), rtol=1e-6,
+                                       atol=0)
 
 
 # ---------------------------------------------------------------------------
@@ -321,8 +323,8 @@ def test_pipeline_sharded_matches_unsharded():
         assert np.array_equal(rs.decisions_np, ru.decisions_np), t
         assert np.array_equal(rs.revenue_np, ru.revenue_np), t
         assert int(rs.downgraded) == int(ru.downgraded), t
-        np.testing.assert_allclose(float(rs.lam_after),
-                                   float(ru.lam_after), rtol=1e-5)
+        # per-option counts make the dual price shard-count invariant
+        assert float(rs.lam_after) == float(ru.lam_after), t
     print("SHARDED SERVING PARITY OK")
     """)], capture_output=True, text=True, timeout=420, env=env)
     assert out.returncode == 0, f"stdout:{out.stdout}\nstderr:{out.stderr}"
