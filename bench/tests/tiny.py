@@ -1,0 +1,34 @@
+"""A cell's configuration and traffic cut to a size a CPU test holds:
+the same files with a small corpus, chain space and windows."""
+from bench import build
+
+
+def config(name: str) -> dict:
+    c = build.load("configs", name)
+    c["world"].update(n_items=200, hist_len=12, n_users=1_000_000)
+    c["chains"].update(n2=[40, 50, 60, 70, 80, 90, 100, 110],
+                       n3=[10, 12, 14, 16, 18, 20, 22, 24], expose=5)
+    for k in ("din", "dien"):
+        c[k].update(item_vocab=200, embed_dim=8, seq_len=12,
+                    attn_hidden=[16, 8], mlp_hidden=[32, 16])
+    c["dssm"].update(item_vocab=200, embed_dim=8, hidden=[16, 8], d_out=8)
+    c["ydnn"].update(item_vocab=200, embed_dim=8, hidden=[16, 8], d_out=8,
+                     hist_len=12)
+    return c
+
+
+def traffic(name: str) -> dict:
+    t = build.load("traffic", name)
+    t.update(users=512, window=64)
+    return t
+
+
+CELLS = {"geotenants-replay-sat": ("greenflow-geotenants",
+                                   "replay-backlog-4096")}
+
+
+def cell(name: str) -> tuple[dict, dict, dict]:
+    """(workload entry, tiny config, tiny traffic) of a cell."""
+    cfg, tr = CELLS[name]
+    w = {"name": name, "config": cfg, "traffic": tr, "chips": 1}
+    return w, config(cfg), traffic(tr)
