@@ -14,9 +14,14 @@ which leaves three artifacts:
       https://ui.perfetto.dev and drag the file in (or
       chrome://tracing).  The serving thread and the chunk-prefetch
       worker render as separate tracks; the per-window ``serve`` spans
-      nest ``h2d`` -> ``dispatch`` -> ``dual_update``, the worker track
-      shows ``prep``/``chunk_tables``, and any serving-thread gap shows
-      up as a ``stall`` span - prefetch working means stalls ~ 0.
+      hold ``h2d`` -> ``dispatch`` -> ``dual_update``, the worker track
+      shows ``prep`` holding ``arrivals`` and then ``chunk_tables`` (a
+      generated source) or ``context_rows`` and ``gather_dispatch`` (a
+      replay source), and any serving-thread gap shows up as a
+      ``stall`` span - prefetch working means stalls ~ 0.  Every span
+      carries ``args.parent`` (the span it sits in) and ``args.t`` (its
+      window), so the worker's ``prep`` of window t and the serving
+      thread's ``serve`` of window t share one id.
   results/obs/metrics.prom(.json)   Prometheus text + JSON snapshot of
       the ``greenflow_*`` registry (windows/requests served, prep /
       stall / submit histograms, h2d bytes, recompiles, per-axis
@@ -27,7 +32,9 @@ which leaves three artifacts:
 
 Add ``--profile-dir /tmp/jaxprof`` to capture a jax.profiler trace of
 the same run (device-side timeline, with the obs span names threaded
-through as TraceAnnotations).
+through as TraceAnnotations).  The device programs are named by the
+program: ``jit_fused_pass`` (the online pass), ``jit_dual_update`` (the
+nearline price update) and, for a replay source, ``jit_replay_gather``.
 
 This script shows the same thing PROGRAMMATICALLY on a toy stream -
 build an ``Obs``, hand it to the source / pipeline / driver, export:

@@ -45,6 +45,8 @@ from collections import OrderedDict
 from concurrent.futures import ThreadPoolExecutor
 from dataclasses import dataclass
 
+import jax
+import jax.numpy as jnp
 import numpy as np
 
 from repro.cascade.engine import (CascadeModels, CompactPlan, _k3_layout,
@@ -52,6 +54,15 @@ from repro.cascade.engine import (CascadeModels, CompactPlan, _k3_layout,
                                   _compact_group_tables_jax, _user_batch,
                                   build_compact_layout)
 from repro.data.synthetic import StreamingWorld, World
+from repro.obs import current
+
+
+@jax.jit
+def replay_gather(table, users):
+    """Rows ``users`` of a device-resident (G, U, cap) replay table, as
+    (G, n, cap); compiled as ``jit_replay_gather``, the name the device
+    trace shows."""
+    return jnp.take(table, users, axis=1)
 
 
 @dataclass
@@ -103,11 +114,19 @@ class RequestSource:
     expose: int = 0
     n_users: int = 0
     seed: int = 0
+    obs = None  # a source built without a bundle records into current()
+
+    def _spans(self):
+        """The bundle this source's spans go to: its own if it records,
+        else the one whose span is open on the calling thread."""
+        obs = self.obs
+        return obs if obs is not None and obs.enabled else current()
 
     def arrivals(self, t: int, n: int) -> np.ndarray:
         """(n,) sampled user ids for window t (uniform arrivals)."""
-        rng = np.random.default_rng((self.seed, t))
-        return rng.integers(0, self.n_users, size=n)
+        with self._spans().span("arrivals", n=n):
+            rng = np.random.default_rng((self.seed, t))
+            return rng.integers(0, self.n_users, size=n)
 
     def window(self, t: int, n: int) -> WindowChunk:
         raise NotImplementedError
@@ -214,8 +233,6 @@ class GeneratedSource(RequestSource):
         """One jitted closure per stage model at the FIXED chunk shape -
         the per-window scoring analogue of the pipeline's bucketed
         padding: any window size reuses the same compiled kernels."""
-        import jax
-        import jax.numpy as jnp
 
         from repro.models.recsys import dien, din, dssm, ydnn
 
@@ -260,8 +277,6 @@ class GeneratedSource(RequestSource):
     def _score_slab(self, slab: World, n_real: int) -> dict:
         """{name: (n_real, I) float np} stage scores for a slab, padded
         to the fixed chunk shape for the jitted kernels."""
-        import jax.numpy as jnp
-
         if self._score_fns is None:
             self._build_score_fns()
         c = self.chunk
@@ -297,8 +312,6 @@ class GeneratedSource(RequestSource):
         arrays (no ``np.asarray`` sync, no host copy) - rows past
         ``n_real`` carry padding garbage the caller slices off on
         device.  Returns ({name: (chunk, I) jax f32}, h2d_bytes)."""
-        import jax.numpy as jnp
-
         if self._score_fns is None:
             self._build_score_fns()
         c = self.chunk
@@ -332,8 +345,6 @@ class GeneratedSource(RequestSource):
     # -- device chunk tables (jitted compaction + slab cache) --------------
 
     def _build_table_fn(self):
-        import jax
-
         lay = self._lay
 
         @jax.jit
@@ -362,8 +373,6 @@ class GeneratedSource(RequestSource):
         scores, h2d = self._score_slab_dev(slab, m)
         if self._table_fn is None:
             self._build_table_fn()
-        import jax.numpy as jnp
-
         clicks = self.world.clicks_slab(ids, slab, pad_rows=self.chunk)
         h2d += clicks.nbytes
         p, ck = self._table_fn(scores, jnp.asarray(clicks))
@@ -413,12 +422,10 @@ class GeneratedSource(RequestSource):
                 tables={"p": np.concatenate(p_parts, axis=1),
                         "ck": np.concatenate(ck_parts, axis=1)},
                 users=users)
-        import jax.numpy as jnp
-
         chunk_ids = [users[lo:lo + self.chunk]
                      for lo in range(0, n, self.chunk)]
-        with self.obs.span("chunk_tables", t=_t, n=n,
-                           chunks=len(chunk_ids)):
+        with self._spans().span("chunk_tables", t=_t, n=n,
+                                chunks=len(chunk_ids)):
             if self.workers > 1 and len(chunk_ids) > 1:
                 if self._pool is None:
                     self._pool = ThreadPoolExecutor(
@@ -517,11 +524,15 @@ class TableReplaySource(RequestSource):
         return self.window_for_users(self.arrivals(t, n))
 
     def window_for_users(self, users: np.ndarray) -> WindowChunk:
+        """Chunk for ``users``; spans ``context_rows`` (the host context
+        gather) and ``gather_dispatch`` (the id upload and the two
+        ``replay_gather`` dispatches)."""
         users = np.asarray(users)
         n = len(users)
+        obs = self._spans()
+        with obs.span("context_rows", n=n):
+            ctx = np.asarray(self.ctx[users], np.float32)
         if self.device_tables:
-            import jax.numpy as jnp
-
             h2d = 0
             if self._dev is None:  # one-time universe upload
                 self._dev = (
@@ -529,16 +540,16 @@ class TableReplaySource(RequestSource):
                     jnp.asarray(np.asarray(self.clicks_sorted,
                                            np.float32)))
                 h2d = int(self._dev[0].nbytes + self._dev[1].nbytes)
-            u = jnp.asarray(users.astype(np.int32))
+            with obs.span("gather_dispatch", n=n):
+                u = jnp.asarray(users.astype(np.int32))
+                tables = {"p": replay_gather(self._dev[0], u),
+                          "ck": replay_gather(self._dev[1], u)}
             h2d += int(u.nbytes)
             return WindowChunk(
-                ctx=np.asarray(self.ctx[users], np.float32),
-                rows=np.arange(n, dtype=np.int32),
-                tables={"p": jnp.take(self._dev[0], u, axis=1),
-                        "ck": jnp.take(self._dev[1], u, axis=1)},
+                ctx=ctx, rows=np.arange(n, dtype=np.int32), tables=tables,
                 users=users, h2d_bytes=h2d)
         return WindowChunk(
-            ctx=np.asarray(self.ctx[users], np.float32),
+            ctx=ctx,
             rows=np.arange(n, dtype=np.int32),
             tables={"p": np.ascontiguousarray(self.p_sorted[:, users]),
                     "ck": np.ascontiguousarray(

@@ -8,10 +8,39 @@ One ``Obs`` bundle threads three views of a run through the stack:
   * ``Obs.tracer`` - host span tracing (``obs.span("prep")``) with a
     Chrome-trace-event exporter; a run opens in Perfetto with the
     serving thread and the ``chunk-prefetch`` thread on separate
-    tracks, so the overlap/stall story is literally visible.
+    tracks, so the overlap/stall story is literally visible.  Every
+    span records its parent (the span open beneath it on its thread)
+    and the window index ``t`` it inherits, exported as ``args.parent``
+    and ``args.t``.
   * ``Obs.events`` - an optional per-window JSONL flight log (size,
     bucket, lam per named axis, spend vs budget per axis, FLOPs,
     gCO2e, h2d bytes, prep/stall/submit ms, recompile deltas).
+
+Spans of one served window (``run_stream`` with a bundle):
+
+=====================  ===============  ================================
+span                   thread           inside
+=====================  ===============  ================================
+``prep``               chunk-prefetch   ``source.window(t, n)``
+``arrivals``           chunk-prefetch   arrival sampling (under prep)
+``context_rows``       chunk-prefetch   replay source: host context
+                                        gather (under prep)
+``gather_dispatch``    chunk-prefetch   replay source: user-id upload +
+                                        two ``replay_gather`` dispatches
+``chunk_tables``       chunk-prefetch   generated source: chunk scoring
+``stall``              serving          wait for the next chunk
+``serve``              serving          ``serve_window``
+``h2d``                serving          window uploads (under serve)
+``dispatch``           serving          the ``fused_pass`` dispatch
+``dual_update``        serving          the ``dual_update`` dispatch
+``block_until_ready``  serving          the final drain
+=====================  ===============  ================================
+
+A component built without a bundle (``TableReplaySource``; any source's
+``arrivals``) records into ``current()``: the bundle whose span is open
+on the calling thread, so the source's spans nest under the stream's
+``prep`` without a bundle being passed through the caller.  With
+telemetry off no span is ever open and ``current()`` is ``NULL_OBS``.
 
 Everything is opt-in and free when off: the shared ``NULL_OBS`` (what
 ``get_obs(None)`` returns, and what every instrumented constructor
@@ -65,8 +94,8 @@ from repro.obs.events import WindowEventLog, window_event
 from repro.obs.env import env_info
 from repro.obs.metrics import (MetricsRegistry, NULL_INSTRUMENT,
                                NULL_REGISTRY, log2_edges)
-from repro.obs.trace import (NULL_SPAN, NULL_TRACER, Tracer,
-                             merge_chrome_traces)
+from repro.obs.trace import (NULL_SPAN, NULL_TRACER, SpanEvent, Tracer,
+                             merge_chrome_traces, open_span)
 
 MS_EDGES = log2_edges(0.25, 8192.0)
 
@@ -90,6 +119,7 @@ class Obs:
         self.metrics = MetricsRegistry() if metrics is None else metrics
         self.tracer = (Tracer(annotate=annotate, process_label=host)
                        if tracer is None else tracer)
+        self.tracer.owner = self
         self.events = events
         self.interval = int(interval)
         self.host = host  # per-host label of a multi-host run
@@ -159,8 +189,18 @@ def get_obs(obs: Obs | None) -> Obs:
     return NULL_OBS if obs is None else obs
 
 
+def current() -> Obs:
+    """The bundle whose span is open on the calling thread (the
+    innermost), else ``NULL_OBS``: what a component built without an
+    ``Obs`` records into."""
+    span = open_span()
+    if span is None or span.tracer.owner is None:
+        return NULL_OBS
+    return span.tracer.owner
+
+
 __all__ = [
-    "Obs", "NULL_OBS", "get_obs",
+    "Obs", "NULL_OBS", "get_obs", "current", "SpanEvent",
     "MetricsRegistry", "NULL_REGISTRY", "NULL_INSTRUMENT", "log2_edges",
     "Tracer", "NULL_TRACER", "NULL_SPAN", "MS_EDGES",
     "merge_chrome_traces",

@@ -420,7 +420,8 @@ class ServingPipeline:
         CI(t); geo pricing: an (R,) scale vector, one per region's
         CI_r(t)) reuse the compiled pass instead of recompiling.
         ``scale`` = 1.0 multiplies bit-exactly, keeping the FLOPs path
-        unchanged.
+        unchanged.  Every variant compiles as ``jit_fused_pass``, the
+        program name the device trace shows.
         """
         axis = AXIS if self.mesh is not None else None
         costs, cheap = self._costs, self._cheap
@@ -444,8 +445,8 @@ class ServingPipeline:
             flow = cs.split == "flow"
             tie_tol = cs.tie_tol
 
-            def fn(params, tables, ctx, rows, valid, k_of, lam, budgets,
-                   scales):
+            def fused_pass(params, tables, ctx, rows, valid, k_of, lam,
+                           budgets, scales):
                 rewards = denormalize_rewards(
                     params, reward_matrix_grouped(
                         params, self.reward_cfg, ctx, self._sh,
@@ -559,20 +560,20 @@ class ServingPipeline:
                         jnp.sum(tr_spend, axis=0), tr_spend)
 
             if self.mesh is not None:
-                fn = jax.shard_map(
-                    fn, mesh=self.mesh, check_vma=False,
+                fused_pass = jax.shard_map(
+                    fused_pass, mesh=self.mesh, check_vma=False,
                     in_specs=(P(), tspec, P(AXIS), P(AXIS), P(AXIS),
                               P(AXIS), P(), P(), P()),
                     out_specs=(P(AXIS), P(AXIS), P(AXIS), P(), P(), P(),
                                P(), P(AXIS), P(), P()))
-            return jax.jit(fn)
+            return jax.jit(fused_pass)
 
         if r_n is not None:
             flow = cs.split == "flow"
             tie_tol = cs.tie_tol
 
-            def fn(params, tables, ctx, rows, valid, lam, budgets,
-                   scales):
+            def fused_pass(params, tables, ctx, rows, valid, lam, budgets,
+                           scales):
                 rewards = denormalize_rewards(
                     params, reward_matrix_grouped(
                         params, self.reward_cfg, ctx, self._sh,
@@ -657,20 +658,20 @@ class ServingPipeline:
                         regions, region_spend)
 
             if self.mesh is not None:
-                fn = jax.shard_map(
-                    fn, mesh=self.mesh, check_vma=False,
+                fused_pass = jax.shard_map(
+                    fused_pass, mesh=self.mesh, check_vma=False,
                     in_specs=(P(), tspec, P(AXIS), P(AXIS), P(AXIS),
                               P(), P(), P()),
                     out_specs=(P(AXIS), P(AXIS), P(AXIS), P(), P(), P(),
                                P(), P(AXIS), P()))
-            return jax.jit(fn)
+            return jax.jit(fused_pass)
 
         if tb is not None:
             t_n = len(tb)
             priced = cs.tenant_priced
 
-            def fn(params, tables, ctx, rows, valid, k_of, lam, budgets,
-                   scale):
+            def fused_pass(params, tables, ctx, rows, valid, k_of, lam,
+                           budgets, scale):
                 rewards = denormalize_rewards(
                     params, reward_matrix_grouped(
                         params, self.reward_cfg, ctx, self._sh,
@@ -702,15 +703,16 @@ class ServingPipeline:
                         None, None)
 
             if self.mesh is not None:
-                fn = jax.shard_map(
-                    fn, mesh=self.mesh, check_vma=False,
+                fused_pass = jax.shard_map(
+                    fused_pass, mesh=self.mesh, check_vma=False,
                     in_specs=(P(), tspec, P(AXIS), P(AXIS), P(AXIS),
                               P(AXIS), P(), P(), P()),
                     out_specs=(P(AXIS), P(AXIS), P(AXIS), P(), P(), P(),
                                P(), P(), P()))
-            return jax.jit(fn)
+            return jax.jit(fused_pass)
 
-        def fn(params, tables, ctx, rows, valid, lam, budget, scale):
+        def fused_pass(params, tables, ctx, rows, valid, lam, budget,
+                       scale):
             rewards = denormalize_rewards(params, reward_matrix_grouped(
                 params, self.reward_cfg, ctx, self._sh, self._prefix_plan))
             costs_eff = costs * scale  # active units (FLOPs or gCO2e)
@@ -731,13 +733,13 @@ class ServingPipeline:
             return rewards, dec, rev, spend, flops, dg, None, None, None
 
         if self.mesh is not None:
-            fn = jax.shard_map(
-                fn, mesh=self.mesh, check_vma=False,
+            fused_pass = jax.shard_map(
+                fused_pass, mesh=self.mesh, check_vma=False,
                 in_specs=(P(), tspec, P(AXIS), P(AXIS), P(AXIS), P(), P(),
                           P()),
                 out_specs=(P(AXIS), P(AXIS), P(AXIS), P(), P(), P(), P(),
                            P(), P()))
-        return jax.jit(fn)
+        return jax.jit(fused_pass)
 
     def _build_dual_fn(self, b: int, padded: bool):
         """Nearline price update: Algorithm 1 on the window's rewards,
@@ -755,7 +757,8 @@ class ServingPipeline:
         dtype, so XLA reuses it in place) and the steady-state chain
         lambda_0 -> lambda_1 -> ... runs allocation-free.  The donated
         buffer is dead afterwards - ``serve_window`` keeps
-        ``self._lam_rec`` as the readable twin for records."""
+        ``self._lam_rec`` as the readable twin for records.  Every
+        variant compiles as ``jit_dual_update``."""
         axis = AXIS if self.mesh is not None else None
 
         def _jit(fn, lam_argnum):
@@ -772,7 +775,7 @@ class ServingPipeline:
             self.tenant_budgets)
 
         if cs.mode == "geotenants":
-            def fn(rewards, valid, k_of, lam, budgets, scales):
+            def dual_update(rewards, valid, k_of, lam, budgets, scales):
                 mask = valid if padded else None
                 opt_costs = (scales[:, None] * costs[None, :]).reshape(-1)
                 cost_map = cs.dual_cost_map(opt_costs, j_n)
@@ -786,14 +789,14 @@ class ServingPipeline:
                 return lam_new
 
             if self.mesh is not None:
-                fn = jax.shard_map(fn, mesh=self.mesh, check_vma=False,
-                               in_specs=(P(AXIS), P(AXIS), P(AXIS), P(),
-                                         P(), P()),
-                               out_specs=P())
-            return _jit(fn, 3)
+                dual_update = jax.shard_map(
+                    dual_update, mesh=self.mesh, check_vma=False,
+                    in_specs=(P(AXIS), P(AXIS), P(AXIS), P(), P(), P()),
+                    out_specs=P())
+            return _jit(dual_update, 3)
 
         if r_n is not None:
-            def fn(rewards, valid, lam, budgets, scales):
+            def dual_update(rewards, valid, lam, budgets, scales):
                 mask = valid if padded else None
                 opt_costs = (scales[:, None] * costs[None, :]).reshape(-1)
                 cost_map = cs.region_cost_map(opt_costs, j_n)
@@ -805,14 +808,14 @@ class ServingPipeline:
                 return lam_new
 
             if self.mesh is not None:
-                fn = jax.shard_map(fn, mesh=self.mesh, check_vma=False,
-                               in_specs=(P(AXIS), P(AXIS), P(), P(),
-                                         P()),
-                               out_specs=P())
-            return _jit(fn, 2)
+                dual_update = jax.shard_map(
+                    dual_update, mesh=self.mesh, check_vma=False,
+                    in_specs=(P(AXIS), P(AXIS), P(), P(), P()),
+                    out_specs=P())
+            return _jit(dual_update, 2)
 
         if priced:
-            def fn(rewards, valid, k_of, lam, budgets, scale):
+            def dual_update(rewards, valid, k_of, lam, budgets, scale):
                 mask = valid if padded else None
                 member = cs.tenant_member(k_of)
                 lam_new, _ = dual_descent(
@@ -823,13 +826,13 @@ class ServingPipeline:
                 return lam_new
 
             if self.mesh is not None:
-                fn = jax.shard_map(fn, mesh=self.mesh, check_vma=False,
-                               in_specs=(P(AXIS), P(AXIS), P(AXIS), P(),
-                                         P(), P()),
-                               out_specs=P())
-            return _jit(fn, 3)
+                dual_update = jax.shard_map(
+                    dual_update, mesh=self.mesh, check_vma=False,
+                    in_specs=(P(AXIS), P(AXIS), P(AXIS), P(), P(), P()),
+                    out_specs=P())
+            return _jit(dual_update, 3)
 
-        def fn(rewards, valid, lam, budget, scale):
+        def dual_update(rewards, valid, lam, budget, scale):
             mask = valid if padded else None
             lam_new, _ = dual_descent(
                 rewards, costs * scale, budget, lam, mask=mask,
@@ -838,10 +841,11 @@ class ServingPipeline:
             return lam_new
 
         if self.mesh is not None:
-            fn = jax.shard_map(fn, mesh=self.mesh, check_vma=False,
-                           in_specs=(P(AXIS), P(AXIS), P(), P(), P()),
-                           out_specs=P())
-        return _jit(fn, 2)
+            dual_update = jax.shard_map(
+                dual_update, mesh=self.mesh, check_vma=False,
+                in_specs=(P(AXIS), P(AXIS), P(), P(), P()),
+                out_specs=P())
+        return _jit(dual_update, 2)
 
     def _bucket(self, n: int) -> int:
         """Pad target for an n-request window.

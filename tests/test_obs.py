@@ -92,7 +92,8 @@ def test_disabled_registry_is_allocation_free():
     import gc
     import tracemalloc
 
-    from repro.obs import NULL_OBS, get_obs
+    import repro.data.request_source as rs
+    from repro.obs import NULL_OBS, current, get_obs
     from repro.obs.metrics import (MetricsRegistry, NULL_INSTRUMENT)
     from repro.obs.trace import NULL_SPAN
 
@@ -104,6 +105,15 @@ def test_disabled_registry_is_allocation_free():
     obs = get_obs(None)
     assert obs is NULL_OBS
     assert obs.span("prep") is NULL_SPAN
+    # a source built without a bundle records into current(): with no
+    # span open, that is the disabled bundle
+    assert current() is NULL_OBS
+
+    class Source(rs.RequestSource):
+        n_users = 100
+
+    src = Source()
+    assert src._spans() is NULL_OBS
 
     def hot():
         for _ in range(2000):
@@ -111,6 +121,11 @@ def test_disabled_registry_is_allocation_free():
             c.inc(7)
             h.observe(3.5)
             with obs.span("prep"):
+                pass
+            src.arrivals(0, 4)
+            with current().span("context_rows", n=4):
+                pass
+            with current().span("gather_dispatch", n=4):
                 pass
 
     hot()  # warm every code path first
@@ -133,7 +148,8 @@ def test_disabled_registry_is_allocation_free():
     retained = sum(
         s.size_diff for s in after.compare_to(before, "lineno")
         if s.size_diff > 0
-        and s.traceback[0].filename.startswith(obs_dir))
+        and (s.traceback[0].filename.startswith(obs_dir)
+             or s.traceback[0].filename == rs.__file__))
     assert retained < 4096, \
         f"disabled telemetry retained {retained} bytes"
     assert obs.tracer.events == []

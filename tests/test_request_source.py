@@ -32,7 +32,9 @@ def replay_source(serving_stack):
     from repro.data.request_source import TableReplaySource
 
     exp, server, _, _ = serving_stack
-    return TableReplaySource.from_server(server, exp.ctx_eval, seed=7)
+    src = TableReplaySource.from_server(server, exp.ctx_eval, seed=7)
+    assert src.device_tables  # windows gather through replay_gather
+    return src
 
 
 def _assert_window_parity(a, b, tag=""):
