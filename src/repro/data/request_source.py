@@ -44,6 +44,7 @@ import threading
 from collections import OrderedDict
 from concurrent.futures import ThreadPoolExecutor
 from dataclasses import dataclass
+from functools import partial
 
 import jax
 import jax.numpy as jnp
@@ -57,12 +58,34 @@ from repro.data.synthetic import StreamingWorld, World
 from repro.obs import current
 
 
-@jax.jit
-def replay_gather(table, users):
-    """Rows ``users`` of a device-resident (G, U, cap) replay table, as
-    (G, n, cap); compiled as ``jit_replay_gather``, the name the device
-    trace shows."""
-    return jnp.take(table, users, axis=1)
+_LANES = 128  # a TPU tile's minor dimension
+
+
+def replay_width(g: int, cap: int) -> int:
+    """Row width of a user-major replay table: G·cap rounded up to whole
+    128-lane tiles.  At such a width the chip lays a (U, W) table out
+    row-major, so a gather of its rows copies nothing else; at another
+    width its layout puts the user axis minor, and every gather would
+    first re-lay the whole table out."""
+    return -(-g * cap // _LANES) * _LANES
+
+
+@partial(jax.jit, static_argnames="width")
+def replay_rows(table, width):
+    """A (G, U, cap) replay table as (U, width): user u's G rows side by
+    side, zero-padded to ``width`` columns."""
+    g, u, cap = table.shape
+    rows = jnp.transpose(table, (1, 0, 2)).reshape(u, g * cap)
+    return jnp.pad(rows, ((0, 0), (0, width - g * cap)))
+
+
+@partial(jax.jit, static_argnames=("g", "cap"))
+def replay_gather(table, users, g, cap):
+    """Rows ``users`` of a device-resident (U, W) ``replay_rows`` table,
+    as the chunk's (G, n, cap); compiled as ``jit_replay_gather``, the
+    name the device trace shows."""
+    rows = jnp.take(table, users, axis=0)[:, :g * cap]
+    return jnp.transpose(rows.reshape(-1, g, cap), (1, 0, 2))
 
 
 @dataclass
@@ -465,9 +488,11 @@ class TableReplaySource(RequestSource):
 
     ``device_tables`` uploads the full tables to the device ONCE and
     turns each window into a device-side row gather - no per-window
-    (G, n, cap) host->device copy.  Default: on for in-memory tables,
-    off for memmapped ones (whose point is that untouched rows never
-    leave the disk).
+    (G, n, cap) host->device copy.  On the device each table is held
+    user-major, (U, W) (``replay_rows``), so that a window's gather
+    reads only its own rows.  Default: on for in-memory tables, off for
+    memmapped ones (whose point is that untouched rows never leave the
+    disk).
     """
 
     def __init__(self, ctx: np.ndarray, p_sorted: np.ndarray,
@@ -535,15 +560,12 @@ class TableReplaySource(RequestSource):
         if self.device_tables:
             h2d = 0
             if self._dev is None:  # one-time universe upload
-                self._dev = (
-                    jnp.asarray(np.asarray(self.p_sorted, np.int32)),
-                    jnp.asarray(np.asarray(self.clicks_sorted,
-                                           np.float32)))
-                h2d = int(self._dev[0].nbytes + self._dev[1].nbytes)
+                h2d = self._upload(obs)
+            g, _, cap = self.p_sorted.shape
             with obs.span("gather_dispatch", n=n):
                 u = jnp.asarray(users.astype(np.int32))
-                tables = {"p": replay_gather(self._dev[0], u),
-                          "ck": replay_gather(self._dev[1], u)}
+                tables = {"p": replay_gather(self._dev[0], u, g, cap),
+                          "ck": replay_gather(self._dev[1], u, g, cap)}
             h2d += int(u.nbytes)
             return WindowChunk(
                 ctx=ctx, rows=np.arange(n, dtype=np.int32), tables=tables,
@@ -555,6 +577,21 @@ class TableReplaySource(RequestSource):
                     "ck": np.ascontiguousarray(
                         self.clicks_sorted[:, users])},
             users=users)
+
+    def _upload(self, obs) -> int:
+        """Put both tables on the device as user-major ``replay_rows``
+        tables, one at a time: each (G, U, cap) original is freed once
+        its re-layout has run.  Span ``table_upload``; returns the bytes
+        sent."""
+        g, _, cap = self.p_sorted.shape
+        sent = 4 * (self.p_sorted.size + self.clicks_sorted.size)
+        with obs.span("table_upload", bytes=sent):
+            self._dev = tuple(
+                replay_rows(jnp.asarray(np.asarray(host, dtype)),
+                            replay_width(g, cap))
+                for host, dtype in ((self.p_sorted, np.int32),
+                                    (self.clicks_sorted, np.float32)))
+        return sent
 
     # -- on-disk (memmap) form -------------------------------------------
 

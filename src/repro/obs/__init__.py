@@ -27,6 +27,9 @@ span                   thread           inside
                                         gather (under prep)
 ``gather_dispatch``    chunk-prefetch   replay source: user-id upload +
                                         two ``replay_gather`` dispatches
+``table_upload``       chunk-prefetch   replay source, first window only:
+                                        both tables uploaded and re-laid
+                                        out user-major (``bytes``)
 ``chunk_tables``       chunk-prefetch   generated source: chunk scoring
 ``stall``              serving          wait for the next chunk
 ``serve``              serving          ``serve_window``

@@ -216,29 +216,45 @@ def replay():
 
 
 def test_replay_source_dispatches_replay_gather(replay, monkeypatch):
+    """Each window gathers both user-major (U, W) device tables through
+    ``jit_replay_gather``, bitwise the host path's rows; the tables go
+    up once, under one ``table_upload`` span, on the first window."""
     import jax.numpy as jnp
 
     import repro.data.request_source as rs
+    from repro.obs import Obs
 
-    _, _, src = replay
+    pipe, ctx, _ = replay
+    src = rs.TableReplaySource.from_server(pipe.server, ctx, seed=9,
+                                           device_tables=True)
     calls = []
     real = rs.replay_gather
 
-    def spy(table, users):
+    def spy(table, users, g, cap):
         calls.append((table.shape, users.shape))
-        return real(table, users)
+        return real(table, users, g, cap)
 
     monkeypatch.setattr(rs, "replay_gather", spy)
-    users = src.arrivals(0, 24)
-    chunk = src.window_for_users(users)
     g, _, cap = src.p_sorted.shape
-    assert calls == [((g, src.n_users, cap), (24,))] * 2
-    np.testing.assert_array_equal(np.asarray(chunk.tables["p"]),
-                                  src.p_sorted[:, users])
-    np.testing.assert_array_equal(np.asarray(chunk.tables["ck"]),
-                                  src.clicks_sorted[:, users])
-    head = real.lower(jnp.asarray(src.p_sorted),
-                      jnp.asarray(users, jnp.int32)).as_text()
+    width = rs.replay_width(g, cap)
+    assert width % 128 == 0 and g * cap <= width < g * cap + 128
+    obs = Obs()
+    for t in range(2):
+        calls.clear()
+        with obs.span("prep", t=t):
+            users = src.arrivals(t, 24)
+            chunk = src.window_for_users(users)
+        assert calls == [((src.n_users, width), (24,))] * 2
+        np.testing.assert_array_equal(np.asarray(chunk.tables["p"]),
+                                      src.p_sorted[:, users])
+        np.testing.assert_array_equal(np.asarray(chunk.tables["ck"]),
+                                      src.clicks_sorted[:, users])
+    uploads = [e for e in obs.tracer.events if e.name == "table_upload"]
+    assert [(e.parent, e.t, e.args) for e in uploads] == [
+        ("prep", 0, {"bytes": src.p_sorted.nbytes
+                     + src.clicks_sorted.nbytes})]
+    head = real.lower(src._dev[0], jnp.asarray(users, jnp.int32),
+                      g, cap).as_text()
     assert head.split(None, 2)[1] == "@jit_replay_gather"
 
 

@@ -133,6 +133,57 @@ def test_memmap_roundtrip_parity(serving_stack, replay_source, tmp_path):
     np.testing.assert_array_equal(a.tables["ck"], b.tables["ck"])
 
 
+@pytest.mark.parametrize("g,cap,width", [(16, 200, 3200), (16, 150, 2432),
+                                         (8, 40, 384), (1, 128, 128)])
+def test_replay_width_rounds_up_to_lanes(g, cap, width):
+    from repro.data.request_source import replay_width
+
+    assert replay_width(g, cap) == width
+
+
+def test_device_tables_with_pad_columns_bitwise(serving_stack):
+    """A universe whose G·cap is not a multiple of 128: the user-major
+    device tables carry zero pad columns, and every window's chunk is
+    bitwise the host path's rows, repeated users and the edge ids 0 and
+    U-1 included."""
+    from repro.cascade.engine import build_compact_layout
+    from repro.data.request_source import TableReplaySource, replay_width
+
+    exp, _, _, _ = serving_stack
+    n_items, expose = exp.cfg.world.n_items, exp.cfg.expose
+    lay = build_compact_layout(exp.chains, n_items=n_items, expose=expose)
+    g, cap = lay.p_sorted.shape[0], lay.cap
+    assert (g * cap) % 128  # the pad engages
+    u_n = 517
+    rng = np.random.default_rng(11)
+    ctx = rng.normal(size=(u_n, 5)).astype(np.float32)
+    p = rng.integers(0, cap, (g, u_n, cap)).astype(np.int32)
+    ck = rng.random((g, u_n, cap)).astype(np.float32)
+    kw = dict(n_items=n_items, expose=expose, seed=3)
+    dev = TableReplaySource(ctx, p, ck, exp.chains, device_tables=True,
+                            **kw)
+    host = TableReplaySource(ctx, p, ck, exp.chains, device_tables=False,
+                             **kw)
+    windows = [dev.arrivals(0, 64), np.array([0, u_n - 1, 0, u_n - 1, 5, 5]),
+               np.array([u_n - 1]), rng.integers(0, u_n, 200)]
+    for t, users in enumerate(windows):
+        a, b = dev.window_for_users(users), host.window_for_users(users)
+        np.testing.assert_array_equal(a.ctx, b.ctx)
+        for key, table in (("p", p), ("ck", ck)):
+            got = np.asarray(a.tables[key])
+            assert got.dtype == table.dtype, (t, key)
+            np.testing.assert_array_equal(got, b.tables[key],
+                                          err_msg=f"w{t} {key}")
+            np.testing.assert_array_equal(got, table[:, users])
+    for d, table in zip(dev._dev, (p, ck)):
+        rows = np.asarray(d)
+        assert rows.shape == (u_n, replay_width(g, cap))
+        assert not rows[:, g * cap:].any()
+        np.testing.assert_array_equal(
+            rows[:, :g * cap],
+            table.transpose(1, 0, 2).reshape(u_n, g * cap))
+
+
 # ---------------------------------------------------------------------------
 # GeneratedSource: determinism, chunk boundaries, streaming world
 # ---------------------------------------------------------------------------

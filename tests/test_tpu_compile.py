@@ -4,14 +4,17 @@ Each test lowers and compiles one program of the main serving path for
 a described v5e chip that is not attached: the Pallas truncation
 kernel, the fused window pass (main + nearline dual) of the plain and
 the geotenants spec at their real bucket sizes, the generated source's
-stage scorers and its device table compactor at the real chunk shape.
-Widths are those of ``experiments.serve_config()``; weights are
-untrained (a compile needs shapes, not values).  Nothing runs: these
-tests catch what the chip's compiler refuses, not wrong results.
+stage scorers and its device table compactor at the real chunk shape,
+and the replay source's table re-layout and row gather at the benchmark
+cell's universe.  Widths are those of ``experiments.serve_config()``;
+weights are untrained (a compile needs shapes, not values).  Nothing
+runs: these tests catch what the chip's compiler refuses, not wrong
+results.
 """
 from __future__ import annotations
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -21,6 +24,7 @@ from jax.sharding import SingleDeviceSharding
 
 CHUNK = 512  # GeneratedSource's scoring chunk
 BATCH = 4096  # requests per CascadeServer.serve call in chip_smoke
+REPLAY_USERS = 131072  # users replayed by the benchmark's replay cell
 
 
 @pytest.fixture(scope="module")
@@ -181,3 +185,41 @@ def test_table_compactor_compiles_for_v5e(one_chip, widths):
                                 sharding=one_chip)
     scores = {k: slab for k in ("DSSM", "YDNN", "DIN", "DIEN")}
     _compile(src._table_fn, scores, slab)
+
+
+def _hlo_buffers(text, op):
+    """Element counts of the buffers that ``op`` instructions output in
+    compiled HLO text."""
+    pat = re.compile(r"= \w+\[([\d,]*)\]\S* " + op + r"\(")
+    return [int(np.prod([int(d) for d in m.group(1).split(",") if d]))
+            for m in pat.finditer(text)]
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.int32])
+@pytest.mark.parametrize("g,cap", [(16, 200), (16, 150)])
+def test_replay_gather_copies_no_table_for_v5e(one_chip, g, cap, dtype):
+    """The replay source's device tables at the cell's universe: the
+    one-time re-layout to (U, W) and the window's row gather.  The chip
+    keeps a (U, W) table row-major once W is whole 128-lane tiles, so
+    the gather copies nothing of the table's size and needs less
+    scratch than the window it returns; at G·cap = 2400 the pad to
+    2432 is what keeps it so."""
+    from repro.data.request_source import (replay_gather, replay_rows,
+                                           replay_width)
+
+    width = replay_width(g, cap)
+    table_elems = g * REPLAY_USERS * cap
+    sds = lambda s, d=dtype: jax.ShapeDtypeStruct(s, d, sharding=one_chip)
+    rows = replay_rows.lower(sds((g, REPLAY_USERS, cap)),
+                             width=width).compile()
+    if g * cap == width:  # lane-dense already: the re-layout is one copy
+        assert rows.memory_analysis().temp_size_in_bytes == 0
+    gather = replay_gather.lower(sds((REPLAY_USERS, width)),
+                                 sds((BATCH,), jnp.int32), g=g,
+                                 cap=cap).compile()
+    text = gather.as_text()
+    assert REPLAY_USERS * width in _hlo_buffers(text, "parameter")
+    moved = _hlo_buffers(text, "copy") + _hlo_buffers(text, "transpose")
+    assert moved and max(moved) < table_elems
+    window_bytes = g * BATCH * cap * np.dtype(dtype).itemsize
+    assert gather.memory_analysis().temp_size_in_bytes < window_bytes
