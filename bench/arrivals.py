@@ -2,7 +2,10 @@
 
 A mix, ``bench/traffic/<mix>.json``, states its traffic as parameters:
 
-- ``source``: ``"replay"`` - requests replay ``users`` per-user tables;
+- ``source``: the request source that makes each window's requests,
+  the module ``bench/sources/<source>.py`` (``"replay"``: requests
+  replay the tables of ``users`` users);
+- ``users``: how many users the source draws requests from;
 - ``window``: the base window size, which the configuration's
   per-window budget is set for;
 - ``arrivals``: how requests reach the system -

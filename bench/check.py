@@ -3,17 +3,8 @@ reference (``bench/reference``), once the timed window has closed.
 
 Numbers, each against the limit in ``bench/limits/<cell>.json`` where
 that file lists it (the readings each limit was set from are recorded
-there too); the others are printed as readings:
+there too); the others are printed as readings.  Every cell has:
 
-- ``spend_over``: every served window, every budget axis of the spec -
-  the spend of the served decisions, recomputed in float64, over the
-  guard's guarantee max(budget, requests x cheapest option), minus 1;
-- ``spend_report``: every served window - the largest relative gap
-  between the spend the program reports per axis and that recomputation;
-- ``region_band``: every served window - how far the priced cost per
-  FLOP of the region a request was served in lies above its cheapest
-  region's, relative, for requests off the cheapest chain (the router
-  may round a tie within the spec's ``tie_tol``);
 - ``decision_regret``, ``decision_flips``, ``decision_gap``: every
   served window, over the requests off the cheapest chain (the guard's
   target, so a request the guard downgraded is never judged) - the
@@ -30,7 +21,13 @@ there too); the others are printed as readings:
 - ``price_stuck``: sampled windows - the share whose published price is
   bit for bit the price they were served with;
 - ``revenue_exec``: sampled windows - served clicks against the clicks
-  the served chain earns on the replay tables.
+  the served chain earns, as the cell's request source computes them.
+
+The cell's budget spec adds its own numbers over every served window
+(``window_numbers`` of ``bench/specs/<kind>.py``), and its request
+source may add more (``numbers``).  The reference's contexts and clicks
+come from the source module, the prices each request faced and
+Algorithm 1 from the spec module.
 
 Sampled windows are drawn from the seed among the windows served.
 """
@@ -43,7 +40,7 @@ from dataclasses import dataclass, field, replace
 import numpy as np
 
 from bench.build import BENCH
-from bench.reference import alloc, cascade
+from bench.reference import alloc
 from bench.reference import chains as ref_chains
 
 SAMPLE_WINDOWS = 4
@@ -101,16 +98,16 @@ class Served:
     revenue: np.ndarray  # (n,)
     lam_before: np.ndarray
     lam_after: np.ndarray
-    spend: np.ndarray | None = None  # reported (T, R)
+    spend: np.ndarray | None = None  # reported by the program
 
 
-def from_program(w) -> Served:
+def from_program(w, spec) -> Served:
     res = w.result
     return Served(k=w.k, users=w.users, decisions=w.decisions,
                   regions=w.regions, revenue=w.revenue,
                   lam_before=np.asarray(res.lam_before, np.float64),
                   lam_after=np.asarray(res.lam_after, np.float64),
-                  spend=np.asarray(res.tr_spend, np.float64))
+                  spend=spec.reported_spend(res))
 
 
 class Reference:
@@ -121,20 +118,16 @@ class Reference:
         self.traffic = traffic
         self.stack = stack
         self.ch = ref_chains.chains(cfg)
-        self.n2_list = sorted(set(int(x) for x in self.ch.n2))
-        self.t_n = cfg["spec"]["tenants"]
+        self.spec = stack.spec_reference(cfg, self.ch)
         self._fns = {}
 
     def window_terms(self, k: int):
         """(budget, scales) of run window k and of the window its
         nearline update aims at (the next one, with the CI forecast)."""
-        st = self.stack
+        st = self.stack.spec
         bud, sc = st.traces(k, 2)
         nxt = 1 if st.forecast else 0
         return (bud[0], sc[0]), (bud[nxt], sc[nxt])
-
-    def tenants(self, n: int) -> np.ndarray:
-        return np.repeat(np.arange(self.t_n), n // self.t_n)
 
     # -- reference computations -----------------------------------------
 
@@ -158,11 +151,10 @@ class Reference:
         import jax.numpy as jnp
 
         fn = self._reward_fn(control)
-        ctx_all = self.stack.replay[0]
+        source = self.stack.source
         for lo in range(0, len(windows), BLOCK):
             block = windows[lo:lo + BLOCK]
-            ctx = np.concatenate([ctx_all[np.asarray(w.users)]
-                                  for w in block])
+            ctx = np.concatenate([source.contexts(w.users) for w in block])
             r = np.asarray(fn(self.stack.reward_params, jnp.asarray(ctx)))
             at = 0
             for w in block:
@@ -170,61 +162,10 @@ class Reference:
                 yield w, r[at:at + n]
                 at += n
 
-    def spend_numbers(self, windows: list) -> tuple[float, float]:
-        """(spend_over, spend_report) over every served window."""
-        c = self.ch.costs
-        over = report = 0.0
-        for w in windows:
-            d = np.asarray(w.decisions)
-            n = len(d)
-            (bud, sc), _ = self.window_terms(w.k)
-            reg = np.asarray(w.regions)
-            ten = self.tenants(n)
-            grams = sc[reg] * c[d]
-            tr = np.zeros((self.t_n, len(sc)))
-            np.add.at(tr, (ten, reg), grams)
-            cheapest = sc.min() * c.min()
-            for t in range(self.t_n):
-                cap = max(bud[t], (ten == t).sum() * cheapest)
-                over = max(over, tr[t].sum() / cap - 1.0)
-            for r in range(len(sc)):
-                cap = max(bud[self.t_n + r],
-                          (reg == r).sum() * sc[r] * c.min())
-                over = max(over, tr[:, r].sum() / cap - 1.0)
-            if w.spend is not None:
-                rep = np.asarray(w.spend, np.float64).reshape(tr.shape)
-                report = max(report, float(np.max(np.abs(rep - tr)))
-                             / max(float(np.max(np.abs(tr))), 1e-30))
-        return over, report
-
-    def region_band(self, w, rewards) -> float:
-        """How far above its cheapest region's the served region's priced
-        cost per FLOP lies, relative, over requests the guard left on a
-        chain other than the cheapest.  The price carries the router's
-        tie-break floor: 1e-6 x max |reward| over the mean option cost,
-        times the region's scale."""
-        (_, sc), _ = self.window_terms(w.k)
-        lam = np.asarray(w.lam_before, np.float64)
-        ten = self.tenants(len(w.decisions))
-        opt = (sc[:, None] * self.ch.costs[None, :]).reshape(-1)
-        eps = 1e-6 * np.abs(rewards).max() / (opt.mean() + 1e-30)
-        u = ((lam[:self.t_n][ten][:, None] + lam[self.t_n:][None, :])
-             + eps) * sc[None, :]
-        best = u.min(1)
-        got = u[np.arange(len(ten)), np.asarray(w.regions)]
-        keep = np.asarray(w.decisions) != self.ch.cheapest
-        return float(np.max(got[keep] / best[keep] - 1)) if keep.any() \
-            else 0.0
-
     def price_per_flop(self, w, n: int) -> np.ndarray:
-        """The per-FLOP price each request's chain choice faced: its
-        cheapest region's."""
-        lam = np.asarray(w.lam_before, np.float64)
+        """The per-FLOP price each request's chain choice faced."""
         (_, sc), _ = self.window_terms(w.k)
-        ten = self.tenants(n)
-        per_flop = (lam[:self.t_n][ten][:, None]
-                    + lam[self.t_n:][None, :]) * sc[None, :]
-        return per_flop.min(1)
+        return self.spec.price_per_flop(w.lam_before, sc, n)
 
     def decisions_at_price(self, w, rewards) -> np.ndarray:
         """Eq. 10 on ``rewards`` at the price window ``w`` was served
@@ -251,34 +192,20 @@ class Reference:
     def nearline(self, w, rewards, fault: str | None = None) -> np.ndarray:
         """Algorithm 1 from ``w``'s entry price on ``rewards``, aimed as
         the configuration aims it; ``fault`` plants one error."""
-        import jax.numpy as jnp
-
         dual = dict(self.cfg["dual"])
         (bud, sc), (d_bud, d_sc) = self.window_terms(w.k)
-        n = len(rewards)
-        weight = np.ones(n, np.float32)
-        ten = self.tenants(n)
+        weight = np.ones(len(rewards), np.float32)
         if fault == "forecast_ignored":  # aims at this window's grams
             d_bud, d_sc = bud, sc
         elif fault == "half_window":  # every other request, counted twice
             weight[1::2], weight[::2] = 0.0, 2.0
         elif fault == "decay_default":  # DualDescentConfig's 0.999
             dual["step_decay"] = 0.999
-        lam = alloc.dual_update(
-            jnp.asarray(rewards, jnp.float32), jnp.asarray(ten, jnp.int32),
-            jnp.asarray(d_sc, jnp.float32),
-            jnp.asarray(self.ch.costs, jnp.float32),
-            jnp.asarray(d_bud, jnp.float32),
-            jnp.asarray(w.lam_before, jnp.float32), jnp.asarray(weight),
-            t_n=self.t_n, iters=int(dual["max_iters"]),
-            step=float(dual["step_size"]), decay=float(dual["step_decay"]))
-        return np.asarray(lam, np.float64)
+        return self.spec.dual_update(rewards, weight, w.lam_before, d_bud,
+                                     d_sc, dual)
 
     def revenue_exec(self, w) -> float:
-        _, p, ck = self.stack.replay
-        users = np.asarray(w.users)
-        want = cascade.table_revenue(self.ch, self.n2_list, p[:, users],
-                                     ck[:, users], w.decisions)
+        want = self.stack.source.revenue(self.ch, w.users, w.decisions)
         return float(np.max(np.abs(np.asarray(w.revenue) - want)))
 
 
@@ -314,18 +241,18 @@ def _decision_numbers(terms: list) -> dict:
 
 def compare(ref: Reference, windows: list, seed: int) -> dict:
     """name -> value for the served ``windows``."""
-    out = {}
-    out["spend_over"], out["spend_report"] = ref.spend_numbers(windows)
+    out = {}  # the spec's worst cases over every window
     picked = set(sample(windows, seed))
-    band, terms, lam, rev = 0.0, [], 0.0, 0.0
+    terms, lam, rev = [], 0.0, 0.0
     for i, (w, r) in enumerate(ref.rewards(windows)):
-        band = max(band, ref.region_band(w, r))
+        (bud, sc), _ = ref.window_terms(w.k)
+        for name, v in ref.spec.window_numbers(w, r, bud, sc).items():
+            out[name] = max(out.get(name, 0.0), v)
         terms.append(ref.decision_terms(w, r))
         if i in picked:
             lam = max(lam, lam_err(w.lam_after, ref.nearline(w, r),
                                    w.lam_before))
             rev = max(rev, ref.revenue_exec(w))
-    out["region_band"] = band
     out.update(_decision_numbers(terms))
     out["lam_err"] = lam
     sampled = [windows[i] for i in sorted(picked)]
@@ -338,9 +265,13 @@ def compare(ref: Reference, windows: list, seed: int) -> dict:
 def run(run, seed: int) -> Verdict:
     """The verdict on one timed run (``measure.Run``)."""
     ref = Reference(run.cfg, run.traffic, run.stack)
-    served = [from_program(w) for w in run.windows]
+    served = [from_program(w, run.stack.spec) for w in run.windows]
     run.stack.release()
-    return verdict(compare(ref, served, seed), limits(run.cell["name"]),
+    values = compare(ref, served, seed)
+    own = getattr(run.stack.source, "numbers", None)
+    if own is not None:
+        values.update(own(run, seed))
+    return verdict(values, limits(run.cell["name"]),
                    [f"check compiles_in_window {run.compiles}"])
 
 
@@ -354,7 +285,7 @@ def control(run, seed: int) -> dict:
     float32 reference exactly as the program is; and ``lam_err`` of the
     reference's own update with each of ``FAULTS`` planted."""
     ref = Reference(run.cfg, run.traffic, run.stack)
-    served = [from_program(w) for w in run.windows]
+    served = [from_program(w, run.stack.spec) for w in run.windows]
     picked = set(sample(served, seed))
     terms, lam = [], 0.0
     faults = {f: 0.0 for f in FAULTS}

@@ -141,14 +141,14 @@ def serve(stack, plan, *, first: int, count: int,
     watcher = Watcher()
     watcher.start()
     clock = plan.clock() if plan.open_loop and t0 is not None else None
-    feed = Feed(stack.source, first, deadline=deadline, clock=clock,
+    feed = Feed(stack.source.program, first, deadline=deadline, clock=clock,
                 t0=t0 or 0.0)
     sizes = [plan.size(first + i) for i in range(count)]
-    budget, scale = stack.traces(first, count)
+    budget, scale = stack.spec.traces(first, count)
     try:
         run_stream(Served(stack.pipe, watcher), sizes, feed,
                    budget_trace=budget, scale_trace=scale,
-                   forecast=stack.forecast, prefetch=2, obs=obs)
+                   forecast=stack.spec.forecast, prefetch=2, obs=obs)
     except Closed:
         pass
     finally:

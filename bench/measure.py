@@ -12,9 +12,7 @@ import importlib.util
 import json
 import os
 import shutil
-from dataclasses import dataclass, field
-
-import numpy as np
+from dataclasses import dataclass
 
 from bench.build import BENCH, ROOT
 
@@ -66,7 +64,6 @@ class Run:
     kind: str
     compiles: int = 0
     trace: object = None  # reduce.Reduced, traced runs
-    _work: dict = field(default_factory=dict)
 
     @property
     def counted(self) -> list:
@@ -89,15 +86,10 @@ class Run:
         return int(sum(w.n - len(w.decisions) for w in self.windows))
 
     def required_flops(self, windows=None) -> float:
-        """The model work the served requests need (``bench.work``):
-        replayed tables come from a cascade that ran upstream, so each
-        request needs the reward model over every chain."""
-        from bench import work
-
-        if not self._work:
-            self._work["per_request"] = work.reward_request(self.cfg)
+        """The model work the served requests need, as the cell's request
+        source counts it (``bench/sources/<source>.py``)."""
         ws = self.counted if windows is None else windows
-        return float(sum(w.n for w in ws)) * self._work["per_request"]
+        return self.stack.source.required_flops(ws)
 
     def peak_flops(self) -> float:
         """The chips' bf16 peak, from ``bench/peaks.json``."""

@@ -94,12 +94,13 @@ def main(argv=None) -> int:
 
 def execute(cell: dict, cfg: dict, traffic: dict, devs, *, seed: int,
             seconds: float, trace: bool, started: float | None = None,
-            check_fn=None):
+            check_fn=None, where: str | None = None):
     """One run of ``cell`` on ``devs``: returns (result line as a dict,
     the lines that print each compared number beside its limit).
     ``started`` is the host-clock instant the process began; set-up is
     counted from there.  ``check_fn`` replaces the comparison with the
-    reference (tests)."""
+    reference (tests).  The cell's source and spec modules are found
+    under ``where`` (``bench/`` by default)."""
     from bench import arrivals, build, drive, measure
 
     if started is None:
@@ -114,7 +115,8 @@ def execute(cell: dict, cfg: dict, traffic: dict, devs, *, seed: int,
 
         obs = Obs(annotate=True)
     plan = arrivals.plan(traffic, seed)
-    stack = build.build(cfg, traffic, seed=seed, chips=chips, obs=obs)
+    stack = build.build(cfg, traffic, seed=seed, chips=chips, obs=obs,
+                        where=where or build.BENCH)
     warm = drive.serve(stack, plan, first=0, count=plan.warmup, obs=obs)
     if trace:
         seconds = min(seconds, TRACE_SECONDS)

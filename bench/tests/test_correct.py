@@ -3,22 +3,25 @@ for a chip skipped) comes out correct on the program as it is, and false
 with the timed path broken underneath, once per fault the cells can
 have; the control (the reference at float8 operands in the program's
 place) fails the cell's limits too."""
+import os
+import textwrap
+
 import jax
 import jax.numpy as jnp
 import pytest
 
-from bench import check
+from bench import build, check
 from bench.run import execute
 from bench.tests import tiny
 
-CELLS = list(tiny.CELLS)
+CELLS = tiny.cells()
 SEED = 2 ** 33 + 17
 
 
-def _run(name, seed=SEED, check_fn=None):
-    w, cfg, tr = tiny.cell(name)
+def _run(name, seed=SEED, check_fn=None, cell=None, where=None):
+    w, cfg, tr = cell or tiny.cell(name)
     out, lines = execute(w, cfg, tr, jax.devices(), seed=seed, seconds=1.5,
-                         trace=False, check_fn=check_fn)
+                         trace=False, check_fn=check_fn, where=where)
     return out, lines
 
 
@@ -69,9 +72,21 @@ def _altered_decision(monkeypatch):
                         lambda *a: real(*a)[:, ::-1])
 
 
-@pytest.mark.parametrize("fault", [_stale_price, _half_window,
-                                   _altered_answer, _altered_decision])
-@pytest.mark.parametrize("name", CELLS)
+# faults that every cell can have, and those of one request source's
+# path (a new source brings its own in a test file of its own)
+FAULTS = (_stale_price, _altered_decision)
+SOURCE_FAULTS = {"replay": (_half_window, _altered_answer)}
+
+
+def _fault_cases():
+    for name in CELLS:
+        source = build.load("traffic", build.workload(name)["traffic"])
+        for fault in FAULTS + SOURCE_FAULTS.get(source["source"], ()):
+            yield pytest.param(name, fault,
+                               id=f"{name}-{fault.__name__}")
+
+
+@pytest.mark.parametrize("name,fault", _fault_cases())
 def test_fault_is_caught(monkeypatch, name, fault):
     fault(monkeypatch)
     out, lines = _run(name)
@@ -92,3 +107,45 @@ def test_control_fails_the_limits(name):
     assert not check.verdict(ctl, check.limits(name)).correct, ctl
     # the planted update fault the price limit was set from reads over it
     assert faults["lam_err.decay_default"] > check.limits(name)["lam_err"]
+
+
+def test_a_cell_from_new_module_files_alone(tmp_path):
+    """A cell whose request source and budget spec are modules that exist
+    only under another directory - thin wrappers over ``replay`` and
+    ``geotenants`` - is built, served and checked, and comes out
+    correct, with no file of ``bench/`` added or changed."""
+    (tmp_path / "sources").mkdir()
+    (tmp_path / "specs").mkdir()
+    (tmp_path / "sources" / "wrapped_replay.py").write_text(textwrap.dedent(
+        """
+        from bench.sources import replay
+
+        cpu_cut = replay.cpu_cut
+
+
+        class Source(replay.Source):
+            pass
+        """))
+    (tmp_path / "specs" / "wrapped_geotenants.py").write_text(
+        textwrap.dedent(
+            """
+            from bench.specs import geotenants
+
+            cpu_cut = geotenants.cpu_cut
+
+
+            class Spec(geotenants.Spec):
+                pass
+
+
+            class Reference(geotenants.Reference):
+                pass
+            """))
+    w, cfg, tr = tiny.cell("geotenants-replay-sat")
+    tr["source"] = "wrapped_replay"
+    cfg["spec"]["kind"] = "wrapped_geotenants"
+    out, lines = _run(w["name"], cell=(w, cfg, tr), where=str(tmp_path))
+    assert out["correct"], "\n".join(lines)
+    assert out["attempted"] > 0 and out["failed"] == 0
+    assert not any(f.startswith("wrapped")
+                   for _, _, files in os.walk(build.BENCH) for f in files)

@@ -9,6 +9,7 @@ import pytest
 from bench import build
 from bench.reference import alloc, models
 from bench.reference import chains as ref_chains
+from bench.sources import replay
 from bench.tests import tiny
 
 
@@ -16,7 +17,7 @@ from bench.tests import tiny
 def setup():
     jax.config.update("jax_default_matmul_precision", "highest")
     cfg = tiny.config("greenflow-geotenants")
-    ctx, _, _ = build.replay_tables(cfg, build.chain_set(cfg), 64, 5)
+    ctx, _, _ = replay.replay_tables(cfg, build.chain_set(cfg), 64, 5)
     yield cfg, ctx
     jax.config.update("jax_default_matmul_precision", None)
 

@@ -62,9 +62,19 @@ def test_chain_work_grows_with_the_chain(cfg):
 
 
 def test_replay_requests_need_the_reward_model_only(cfg):
-    from bench.measure import Run
+    from types import SimpleNamespace
 
-    run = Run(cell={}, cfg=cfg, traffic={}, stack=None, windows=[], t0=0.0,
+    from bench import build
+    from bench.measure import Run
+    from bench.sources import replay
+
+    tr = tiny.traffic("replay-backlog-4096")
+    replay.cpu_cut(cfg, tr)
+    source = replay.Source(cfg, tr, build.chain_set(cfg), seed=3)
+    run = Run(cell={}, cfg=cfg, traffic=tr,
+              stack=SimpleNamespace(source=source), windows=[], t0=0.0,
               t_end=1.0, seconds=1.0, setup_s=0.0, chips=1, kind="")
     assert run.required_flops([]) == 0.0
     assert work.reward_request(cfg) > 0
+    windows = [SimpleNamespace(n=4096), SimpleNamespace(n=64)]
+    assert run.required_flops(windows) == 4160 * work.reward_request(cfg)

@@ -1,5 +1,9 @@
 """A cell's configuration and traffic cut to a size a CPU test holds:
-the same files with a small corpus, chain space and windows."""
+the same files with a small corpus, chain space and windows, then the
+cut of the cell's own request source and budget spec modules."""
+import json
+import os
+
 from bench import build
 
 
@@ -19,16 +23,20 @@ def config(name: str) -> dict:
 
 def traffic(name: str) -> dict:
     t = build.load("traffic", name)
-    t.update(users=512, window=64)
+    t.update(window=64)
     return t
 
 
-CELLS = {"geotenants-replay-sat": ("greenflow-geotenants",
-                                   "replay-backlog-4096")}
+def cells() -> list[str]:
+    """The names of ``BENCHMARK.json``'s cells."""
+    with open(os.path.join(build.ROOT, "BENCHMARK.json")) as f:
+        return [w["name"] for w in json.load(f)["workloads"]]
 
 
 def cell(name: str) -> tuple[dict, dict, dict]:
     """(workload entry, tiny config, tiny traffic) of a cell."""
-    cfg, tr = CELLS[name]
-    w = {"name": name, "config": cfg, "traffic": tr, "chips": 1}
-    return w, config(cfg), traffic(tr)
+    w = build.workload(name)
+    cfg, tr = config(w["config"]), traffic(w["traffic"])
+    build.module("sources", tr["source"]).cpu_cut(cfg, tr)
+    build.module("specs", cfg["spec"]["kind"]).cpu_cut(cfg)
+    return dict(w, chips=1), cfg, tr
